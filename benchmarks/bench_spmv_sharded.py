@@ -45,6 +45,7 @@ from repro.core.formats import RgCSR, ShardedRgCSR   # noqa: E402
 from repro.core.suite import generate                # noqa: E402
 from repro.kernels import autotune                   # noqa: E402
 from repro.kernels import ops as kops                # noqa: E402
+from repro.launch.mesh import make_mesh              # noqa: E402
 from repro.sharding import Partitioner               # noqa: E402
 
 # n=1024 on 8 devices → 128 rows/shard = exactly one full 128-lane group,
@@ -163,7 +164,7 @@ def main(argv=None) -> int:
         print(f"# need 8 devices, got {n_dev} — set XLA_FLAGS="
               f"--xla_force_host_platform_device_count=8", file=sys.stderr)
         return 1
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = make_mesh((1, 8), ("data", "model"))
     axis = Partitioner(mesh, "decode").spmv_shard_axis()
     assert axis == "model", axis
     d = int(mesh.shape[axis])

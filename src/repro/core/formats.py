@@ -297,35 +297,44 @@ class HybridEllCoo:
     name: ClassVar[str] = "hybrid"
 
     @classmethod
-    def from_dense(cls, dense: np.ndarray, k1: int | None = None) -> "HybridEllCoo":
-        dense = _as_2d(dense)
-        n_rows, _ = dense.shape
-        row_lens = (dense != 0).sum(axis=1)
+    def from_csr(cls, values, columns, row_ptr, shape: Tuple[int, int],
+                 k1: int | None = None) -> "HybridEllCoo":
+        """Build from a host CSR triplet (vectorized, no densification):
+        each row's first ``k1`` entries go to the ELL part, the rest to
+        the COO tail in CSR order."""
+        row_ptr = np.asarray(row_ptr).astype(np.int64)
+        nnz = int(row_ptr[-1])
+        values = np.asarray(values)[:nnz]
+        columns = np.asarray(columns)[:nnz].astype(np.int32)
+        n_rows = int(shape[0])
+        row_lens = np.diff(row_ptr)
         if k1 is None:
             k1 = _hybrid_split_k(row_lens)
         k1 = int(max(k1, 0))
-        ell_values = np.zeros((max(k1, 1), n_rows), dtype=dense.dtype)
+        rows = np.repeat(np.arange(n_rows, dtype=np.int64), row_lens)
+        slot = np.arange(nnz, dtype=np.int64) - np.repeat(row_ptr[:-1],
+                                                          row_lens)
+        head = slot < k1
+        ell_values = np.zeros((max(k1, 1), n_rows), dtype=values.dtype)
         ell_columns = np.zeros((max(k1, 1), n_rows), dtype=np.int32)
-        coo_v, coo_r, coo_c = [], [], []
-        for i in range(n_rows):
-            cols_i = np.nonzero(dense[i])[0]
-            head = cols_i[:k1]
-            tail = cols_i[k1:]
-            ell_values[: len(head), i] = dense[i, head]
-            ell_columns[: len(head), i] = head
-            coo_v.extend(dense[i, tail])
-            coo_r.extend([i] * len(tail))
-            coo_c.extend(tail)
-        coo_dtype = dense.dtype
+        ell_values[slot[head], rows[head]] = values[head]
+        ell_columns[slot[head], rows[head]] = columns[head]
+        tail = ~head
         return cls(
             ell_values=jnp.asarray(ell_values),
             ell_columns=jnp.asarray(ell_columns),
-            coo_values=jnp.asarray(np.asarray(coo_v, dtype=coo_dtype)),
-            coo_rows=jnp.asarray(np.asarray(coo_r, dtype=np.int32)),
-            coo_columns=jnp.asarray(np.asarray(coo_c, dtype=np.int32)),
-            shape=dense.shape,
+            coo_values=jnp.asarray(values[tail]),
+            coo_rows=jnp.asarray(rows[tail].astype(np.int32)),
+            coo_columns=jnp.asarray(columns[tail]),
+            shape=(n_rows, int(shape[1])),
             k1=k1,
         )
+
+    @classmethod
+    def from_dense(cls, dense: np.ndarray, k1: int | None = None) -> "HybridEllCoo":
+        dense = _as_2d(dense)
+        values, cols, _, row_ptr = _csr_arrays(dense)
+        return cls.from_csr(values, cols, row_ptr, dense.shape, k1=k1)
 
     @property
     def nnz(self) -> int:
@@ -431,8 +440,10 @@ class BlockedCSR:
 # ---------------------------------------------------------------------------
 
 
-def _rgcsr_arrays(dense: np.ndarray, group_size: int, slot_pad: int):
-    """Build slot-major grouped arrays. Returns a dict of numpy arrays.
+def _rgcsr_arrays(values, columns, row_ptr, n_rows: int, group_size: int,
+                  slot_pad: int):
+    """Build slot-major grouped arrays from a host CSR triplet (vectorized).
+    Returns a dict of numpy arrays.
 
     Layout: group ``g`` covers rows ``[g*G, min((g+1)*G, N))``; its data is a
     dense ``(K_g, G)`` tile flattened into ``values``/``columns`` starting at
@@ -441,53 +452,59 @@ def _rgcsr_arrays(dense: np.ndarray, group_size: int, slot_pad: int):
     rounded up to ``slot_pad`` (TPU sublane packing; paper pads to the max
     row length only — the extra pad is accounted as artificial zeros too).
     The last group is padded to a full ``G`` rows (lanes must be full).
+    Row ``r``'s CSR entries fill slots ``0..len-1`` of its lane in CSR order.
     """
-    dense = _as_2d(dense)
-    n_rows = dense.shape[0]
+    values = np.asarray(values)
+    columns = np.asarray(columns)
+    row_ptr = np.asarray(row_ptr).astype(np.int64)
+    if len(row_ptr) != n_rows + 1:
+        raise ValueError(f"row_ptr has {len(row_ptr)} entries for "
+                         f"{n_rows} rows")
     g_size = int(group_size)
     n_groups = max(1, -(-n_rows // g_size))
-    row_lens = (dense != 0).sum(axis=1).astype(np.int32)
+    row_lens = np.diff(row_ptr).astype(np.int32)
 
-    group_ptr = np.zeros(n_groups + 1, dtype=np.int64)
-    slots_per_group = np.zeros(n_groups, dtype=np.int32)
-    for g in range(n_groups):
-        lo, hi = g * g_size, min((g + 1) * g_size, n_rows)
-        k_g = int(row_lens[lo:hi].max()) if hi > lo else 0
-        if slot_pad > 1:
-            k_g = -(-max(k_g, 1) // slot_pad) * slot_pad
-        else:
-            k_g = max(k_g, 1)
-        slots_per_group[g] = k_g
-        group_ptr[g + 1] = group_ptr[g] + k_g * g_size
-
+    lens_grid = np.zeros(n_groups * g_size, np.int64)
+    lens_grid[:n_rows] = row_lens
+    k_g = np.maximum(lens_grid.reshape(n_groups, g_size).max(axis=1), 1)
+    if slot_pad > 1:
+        k_g = -(-k_g // slot_pad) * slot_pad
+    slots_per_group = k_g.astype(np.int32)
+    group_ptr = np.concatenate([[0], np.cumsum(k_g * g_size)])
     total = int(group_ptr[-1])
-    values = np.zeros(total, dtype=dense.dtype)
-    columns = np.zeros(total, dtype=np.int32)
-    row_of_element = np.zeros(total, dtype=np.int32)  # derived (oracle only)
-    for g in range(n_groups):
-        lo, hi = g * g_size, min((g + 1) * g_size, n_rows)
-        base = int(group_ptr[g])
-        k_g = int(slots_per_group[g])
-        # default the padding's row-ids to the group's first row; values are 0
-        row_of_element[base: base + k_g * g_size] = lo if hi > lo else 0
-        for r in range(lo, hi):
-            cols_r = np.nonzero(dense[r])[0]
-            lane = r - lo
-            idx = base + np.arange(len(cols_r)) * g_size + lane
-            values[idx] = dense[r, cols_r]
-            columns[idx] = cols_r
-            pad_idx = base + np.arange(len(cols_r), k_g) * g_size + lane
-            row_of_element[base + np.arange(k_g) * g_size + lane] = r
-            columns[pad_idx] = 0  # ghost index (paper: "ghost index")
+
+    rows = np.repeat(np.arange(n_rows, dtype=np.int64), row_lens)
+    slot = np.arange(len(rows), dtype=np.int64) - np.repeat(row_ptr[:-1],
+                                                           row_lens)
+    flat = group_ptr[rows // g_size] + slot * g_size + rows % g_size
+    out_values = np.zeros(total, dtype=values.dtype)
+    out_columns = np.zeros(total, dtype=np.int32)     # ghost index 0 on pad
+    out_values[flat] = values[: len(rows)]
+    out_columns[flat] = columns[: len(rows)]
+
+    # derived (oracle only): row of every stored element; lanes past the
+    # last real row default to their group's first row (values are 0)
+    grp = np.repeat(np.arange(n_groups, dtype=np.int64), k_g * g_size)
+    lane = (np.arange(total, dtype=np.int64) - group_ptr[grp]) % g_size
+    row_of_element = grp * g_size + lane
+    row_of_element = np.where(row_of_element < n_rows, row_of_element,
+                              grp * g_size).astype(np.int32)
     return dict(
-        values=values,
-        columns=columns,
+        values=out_values,
+        columns=out_columns,
         group_pointers=group_ptr.astype(np.int32),
         row_lengths=row_lens,
         slots_per_group=slots_per_group,
         row_of_element=row_of_element,
         n_groups=n_groups,
     )
+
+
+def _dense_rgcsr_arrays(dense, group_size: int, slot_pad: int):
+    dense = _as_2d(dense)
+    values, cols, _, row_ptr = _csr_arrays(dense)
+    return _rgcsr_arrays(values, cols, row_ptr, dense.shape[0], group_size,
+                         slot_pad)
 
 
 @_tree_dataclass
@@ -518,10 +535,15 @@ class RgCSR:
     name: ClassVar[str] = "rgcsr"
 
     @classmethod
-    def from_dense(cls, dense: np.ndarray, group_size: int = TPU_LANES,
-                   slot_pad: int = TPU_SUBLANES) -> "RgCSR":
-        dense = _as_2d(dense)
-        arrs = _rgcsr_arrays(dense, group_size, slot_pad)
+    def from_csr(cls, values, columns, row_ptr, shape: Tuple[int, int],
+                 group_size: int = TPU_LANES,
+                 slot_pad: int = TPU_SUBLANES) -> "RgCSR":
+        """Build from a host CSR triplet without densifying — the one
+        construction path (``from_dense`` goes through it), so matrices
+        far larger than a dense host array can reach the kernel."""
+        shape = (int(shape[0]), int(shape[1]))
+        arrs = _rgcsr_arrays(values, columns, row_ptr, shape[0], group_size,
+                             slot_pad)
         return cls(
             values=jnp.asarray(arrs["values"]),
             columns=jnp.asarray(arrs["columns"]),
@@ -529,10 +551,18 @@ class RgCSR:
             row_lengths=jnp.asarray(arrs["row_lengths"]),
             slots_per_group=jnp.asarray(arrs["slots_per_group"]),
             row_of_element=jnp.asarray(arrs["row_of_element"]),
-            shape=dense.shape,
+            shape=shape,
             group_size=int(group_size),
             slot_pad=int(slot_pad),
         )
+
+    @classmethod
+    def from_dense(cls, dense: np.ndarray, group_size: int = TPU_LANES,
+                   slot_pad: int = TPU_SUBLANES) -> "RgCSR":
+        dense = _as_2d(dense)
+        values, cols, _, row_ptr = _csr_arrays(dense)
+        return cls.from_csr(values, cols, row_ptr, dense.shape,
+                            group_size=group_size, slot_pad=slot_pad)
 
     @property
     def n_groups(self) -> int:
@@ -617,7 +647,7 @@ class SlicedEllpack:
     @classmethod
     def from_dense(cls, dense: np.ndarray, group_size: int = TPU_LANES,
                    slot_pad: int = TPU_SUBLANES) -> "SlicedEllpack":
-        arrs = _rgcsr_arrays(_as_2d(dense), group_size, slot_pad)
+        arrs = _dense_rgcsr_arrays(dense, group_size, slot_pad)
         return cls(
             values=jnp.asarray(arrs["values"]),
             columns=jnp.asarray(arrs["columns"]),
@@ -702,23 +732,39 @@ class ShardedRgCSR:
                 max(1, -(-n_cols // n_shards)))
 
     @classmethod
+    def from_csr(cls, values, columns, row_ptr, shape: Tuple[int, int],
+                 n_shards: int, group_size: int = TPU_LANES,
+                 slot_pad: int = TPU_SUBLANES) -> "ShardedRgCSR":
+        """Row-shard a host CSR triplet (no densification): shard ``d``
+        gets rows ``[d·rps, (d+1)·rps)`` as its own :class:`RgCSR`."""
+        values = np.asarray(values)
+        columns = np.asarray(columns)
+        row_ptr = np.asarray(row_ptr).astype(np.int64)
+        n_rows, n_cols = int(shape[0]), int(shape[1])
+        rps, _ = cls.shard_layout(n_rows, n_cols, n_shards)
+        shards = []
+        for d in range(n_shards):
+            lo, hi = min(d * rps, n_rows), min((d + 1) * rps, n_rows)
+            ptr = np.zeros(rps + 1, np.int64)
+            ptr[1: hi - lo + 1] = row_ptr[lo + 1: hi + 1] - row_ptr[lo]
+            ptr[hi - lo + 1:] = ptr[hi - lo]       # trailing empty rows
+            sel = slice(int(row_ptr[lo]), int(row_ptr[hi]))
+            shards.append(RgCSR.from_csr(values[sel], columns[sel], ptr,
+                                         (rps, n_cols),
+                                         group_size=group_size,
+                                         slot_pad=slot_pad))
+        return cls(shards=tuple(shards), shape=(n_rows, n_cols),
+                   n_shards=int(n_shards), rows_per_shard=rps,
+                   group_size=int(group_size), slot_pad=int(slot_pad))
+
+    @classmethod
     def from_dense(cls, dense: np.ndarray, n_shards: int,
                    group_size: int = TPU_LANES,
                    slot_pad: int = TPU_SUBLANES) -> "ShardedRgCSR":
         dense = _as_2d(dense)
-        n_rows, n_cols = dense.shape
-        rps, _ = cls.shard_layout(n_rows, n_cols, n_shards)
-        shards = []
-        for d in range(n_shards):
-            lo, hi = d * rps, min((d + 1) * rps, n_rows)
-            block = np.zeros((rps, n_cols), dtype=dense.dtype)
-            if hi > lo:
-                block[: hi - lo] = dense[lo:hi]
-            shards.append(RgCSR.from_dense(block, group_size=group_size,
-                                           slot_pad=slot_pad))
-        return cls(shards=tuple(shards), shape=dense.shape,
-                   n_shards=int(n_shards), rows_per_shard=rps,
-                   group_size=int(group_size), slot_pad=int(slot_pad))
+        values, cols, _, row_ptr = _csr_arrays(dense)
+        return cls.from_csr(values, cols, row_ptr, dense.shape, n_shards,
+                            group_size=group_size, slot_pad=slot_pad)
 
     @property
     def nnz(self) -> int:

@@ -175,30 +175,43 @@ def _identity(x):
     return x
 
 
-def _use_kernel(a, impl: str) -> bool:
+def _use_kernel(a, impl: str, kernel_formats=(RgCSR,)) -> bool:
     """Kernel dispatch policy.
 
     ``impl='ref'`` — always the jnp oracle.  ``impl='kernel'`` — the Pallas
-    kernel via the process-wide PlanCache (interpret mode on CPU).
-    ``impl='auto'`` — kernel on TPU, oracle elsewhere.  Kernel dispatch is
-    host-side (plans index host metadata), so it requires concrete arrays:
-    under jit tracing auto/kernel fall back to the oracle, which XLA shards
-    and fuses like any segment-sum.
+    kernel (interpret mode off the TPU); a matrix no kernel can run raises
+    instead of silently answering from the oracle: a format without a
+    kernel (``kernel_formats``), or a matrix traced under ``jit`` (kernel
+    plans index host metadata, so the matrix must be concrete — close over
+    it rather than passing it as a jit argument).  ``impl='auto'`` — the
+    kernel on TPU for RgCSR matrices it can run, the oracle elsewhere
+    (including under tracing, where XLA shards and fuses the segment-sum).
     """
     if impl not in ("auto", "ref", "kernel"):   # validate unconditionally,
         raise ValueError(                        # even on oracle-only paths
             f"unknown impl {impl!r}; options: auto/ref/kernel")
-    if impl == "ref" or not isinstance(a, RgCSR):
+    if impl == "ref":
         return False
-    if isinstance(a.values, jax.core.Tracer):
-        return False
+    traced = any(isinstance(leaf, jax.core.Tracer)
+                 for leaf in jax.tree_util.tree_leaves(a))
     if impl == "kernel":
+        if not isinstance(a, kernel_formats):
+            raise ValueError(
+                f"impl='kernel': no Pallas kernel for {type(a).__name__} "
+                f"here (kernels: "
+                f"{', '.join(f.__name__ for f in kernel_formats)})")
+        if traced:
+            raise ValueError(
+                "impl='kernel' needs a concrete matrix: its kernel plan is "
+                "built on the host, so the matrix cannot be a jit argument "
+                "(close over it, or use impl='auto'/'ref')")
         return True      # explicit request: let make_plan raise if unrunnable
     # auto: only matrices the TPU kernel can actually run (group_size a
     # multiple of 128 lanes, slots sublane-packed); others — e.g. the small
     # modeled group sizes the format tests sweep — stay on the oracle
     # instead of crashing in make_plan.
-    return (jax.default_backend() == "tpu"
+    return (isinstance(a, RgCSR) and not traced
+            and jax.default_backend() == "tpu"
             and a.group_size % 128 == 0 and a.slot_pad % 8 == 0)
 
 
@@ -231,7 +244,9 @@ def spmv(a: Matrix, x, *, impl: str = "auto", chunks_per_step: int = 1,
     RgCSR matrices can dispatch to the Pallas kernel through the process-wide
     :data:`repro.kernels.ops.PLAN_CACHE` (see ``impl`` in :func:`_use_kernel`)
     so repeated SpMV on the same matrix — the serving / iterative-solver
-    pattern — builds its host-side execution plan exactly once.
+    pattern — builds its host-side execution plan exactly once.  ELLPACK
+    and Hybrid matrices run the ELL kernel under ``impl='kernel'`` (plus
+    the Hybrid's COO tail as a segment-sum).
 
     ``ordering='adaptive'`` selects the length-aware regrouped plan (and,
     with ``spill_threshold > 0``, the pathological-row COO spill); results
@@ -252,8 +267,10 @@ def spmv(a: Matrix, x, *, impl: str = "auto", chunks_per_step: int = 1,
                                        ordering, spill_threshold, x_mode,
                                        shard_configs)
         return kops.sharded_rgcsr_spmv(plan, x, mesh=mesh, axis=axis)
-    if _use_kernel(a, impl):
+    if _use_kernel(a, impl, (RgCSR, ELLPACK, HybridEllCoo)):
         from repro.kernels import ops as kops
+        if not isinstance(a, RgCSR):
+            return kops.hybrid_spmv(a, x)
         plan = kops.get_plan(a, chunks_per_step=chunks_per_step,
                              ordering=ordering,
                              spill_threshold=spill_threshold)

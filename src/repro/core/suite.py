@@ -27,7 +27,8 @@ from typing import Callable, Dict, Iterator, List, Tuple
 
 import numpy as np
 
-__all__ = ["MatrixSpec", "generate", "corpus", "small_corpus", "paper_twins"]
+__all__ = ["MatrixSpec", "generate", "corpus", "small_corpus", "paper_twins",
+           "stencil27_csr"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,6 +67,37 @@ def _stencil(n: int, seed: int, points: int = 5) -> np.ndarray:
         else:
             a[np.arange(-off, n), np.arange(n + off)] = diag
     return a
+
+
+def stencil27_csr(grid, seed: int = 0):
+    """27-point stencil on a 3-D grid as a host CSR triplet, built without a
+    dense array: the operator structure of the HPCG benchmark.
+
+    ``grid`` is ``(nz, ny, nx)`` or one int for a cube.  Row ``r = (z, y,
+    x)`` couples to every in-grid point of its 3×3×3 neighbourhood, so
+    interior rows hold 27 nonzeros and boundary rows fewer; a 64³ grid
+    gives 262,144 rows (the size of the paper's largest matrix, Raj1) and
+    6,859,000 nonzeros.  Columns are ascending within each row; values are
+    uniform in [0.5, 1.5) from ``seed``.  Returns ``(values float32,
+    columns int32, row_ptr int64, shape)``.
+    """
+    nz, ny, nx = (grid,) * 3 if np.isscalar(grid) else tuple(grid)
+    n = nz * ny * nx
+    z, y, x = np.unravel_index(np.arange(n, dtype=np.int64), (nz, ny, nx))
+    cols = np.empty((n, 27), np.int64)
+    valid = np.empty((n, 27), bool)
+    offsets = [(dz, dy, dx) for dz in (-1, 0, 1) for dy in (-1, 0, 1)
+               for dx in (-1, 0, 1)]          # lexicographic = ascending col
+    for i, (dz, dy, dx) in enumerate(offsets):
+        zz, yy, xx = z + dz, y + dy, x + dx
+        valid[:, i] = ((zz >= 0) & (zz < nz) & (yy >= 0) & (yy < ny)
+                       & (xx >= 0) & (xx < nx))
+        cols[:, i] = (zz * ny + yy) * nx + xx
+    row_ptr = np.concatenate([[0], np.cumsum(valid.sum(axis=1))])
+    columns = cols[valid].astype(np.int32)
+    values = _rng(seed).uniform(0.5, 1.5, size=len(columns)
+                                ).astype(np.float32)
+    return values, columns, row_ptr, (n, n)
 
 
 def _fem2d(n: int, seed: int) -> np.ndarray:

@@ -2,11 +2,11 @@
 
 ELLPACK is the degenerate RgCSR with a single group = the whole matrix, so
 the kernel is the same slot-major FMA without any chunk table: grid
-``(col_tiles, slot_tiles)`` with the slot dim innermost so each output tile
-accumulates consecutively.  Used by the Hybrid format's ELL part; the COO
-spill runs as a jnp segment-sum (irregular scatter has no efficient TPU
-kernel — that asymmetry is itself a finding the paper's GPU Hybrid did not
-have, recorded in EXPERIMENTS.md §Table3).
+``(row_tiles, slot_tiles)`` with the slot dim innermost so each output tile
+accumulates consecutively.  As in the RgCSR kernel, ``x[columns]`` is
+gathered by XLA before the kernel (the TPU compiler has no general
+in-kernel gather).  Used by the Hybrid format's ELL part; the COO spill
+runs as a jnp segment-sum.
 """
 from __future__ import annotations
 
@@ -22,39 +22,37 @@ LANES = 128
 __all__ = ["ell_spmv_kernel", "ell_spmv_pallas"]
 
 
-def ell_spmv_kernel(values_ref, columns_ref, x_ref, y_ref):
-    """Blocks: values/columns (8, R); x (1, n_pad); y (1, R)."""
+def ell_spmv_kernel(values_ref, xg_ref, y_ref):
+    """Blocks: values and gathered x (8, row_tile); y (1, row_tile) f32."""
     k = pl.program_id(1)
 
     @pl.when(k == 0)
     def _init():
         y_ref[...] = jnp.zeros_like(y_ref)
 
-    vals = values_ref[...]                          # (8, R)
-    cols = columns_ref[...]
-    x = x_ref[0, :]
-    gathered = jnp.take(x, cols.reshape(-1), axis=0).reshape(cols.shape)
-    y_ref[...] += jnp.sum(vals * gathered, axis=0, keepdims=True)
+    prods = (values_ref[...].astype(jnp.float32)
+             * xg_ref[...].astype(jnp.float32))
+    y_ref[...] += jnp.sum(prods, axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("row_tile", "interpret"))
-def ell_spmv_pallas(values2d, columns2d, x_pad, *, row_tile: int = LANES,
+def ell_spmv_pallas(values2d, columns2d, x, *, row_tile: int = LANES,
                     interpret: bool = True):
-    """values2d/columns2d: (K_pad, N_pad) slot-major; x_pad: (1, n_pad).
-    Returns (1, N_pad)."""
+    """values2d/columns2d: (K_pad, N_pad) slot-major; x: (n_cols,).
+    Returns (N_pad,) in the result dtype of ``values2d`` and ``x``."""
     k_pad, n_rows_pad = values2d.shape
-    slot_tiles = k_pad // SUBLANES
-    row_tiles = n_rows_pad // row_tile
-
-    return pl.pallas_call(
+    out_dtype = jnp.result_type(values2d.dtype, x.dtype)
+    xg = jnp.take(x, columns2d, axis=0)                   # XLA gather
+    y = pl.pallas_call(
         ell_spmv_kernel,
-        grid=(row_tiles, slot_tiles),
+        grid=(n_rows_pad // row_tile, k_pad // SUBLANES),
         in_specs=[
             pl.BlockSpec((SUBLANES, row_tile), lambda r, k: (k, r)),
             pl.BlockSpec((SUBLANES, row_tile), lambda r, k: (k, r)),
-            pl.BlockSpec((1, x_pad.shape[1]), lambda r, k: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, row_tile), lambda r, k: (0, r)),
-        out_shape=jax.ShapeDtypeStruct((1, n_rows_pad), values2d.dtype),
+        out_shape=jax.ShapeDtypeStruct((1, n_rows_pad), jnp.float32),
         interpret=interpret,
-    )(values2d, columns2d, x_pad)
+        name="ell_spmv",
+    )(values2d, xg)
+    return y[0].astype(out_dtype)

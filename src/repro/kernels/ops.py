@@ -14,9 +14,10 @@ serving engine, the benchmark harness) fetch plans through ``get_plan``
 instead of rebuilding host-side layouts per call.  Entries are keyed on
 matrix identity + config and evicted when the matrix is garbage-collected.
 
-On CPU (this container) the kernels run in ``interpret=True`` mode — the
-kernel body executes in Python with identical semantics; on a real TPU pass
-``interpret=False`` (the default resolves via ``jax.default_backend()``).
+On a TPU the kernels compile through Mosaic; on any other backend they run
+in ``interpret=True`` mode — the kernel body executes in Python with
+identical semantics (the CPU test path).  ``interpret=None`` resolves via
+``jax.default_backend()``.
 """
 from __future__ import annotations
 
@@ -31,20 +32,20 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.formats import ELLPACK, RgCSR, ShardedRgCSR
+from repro.core.formats import ELLPACK, HybridEllCoo, RgCSR, ShardedRgCSR
 from repro.kernels.ell_spmv import ell_spmv_pallas
 from repro.kernels.rgcsr_spmm import rgcsr_spmm_pallas
 from repro.kernels.rgcsr_spmv import (CHUNKS_PER_STEP_CHOICES, LANES,
                                       SUBLANES, rgcsr_spmv_pallas)
 
 __all__ = ["RgCSRPlan", "make_plan", "rgcsr_spmv", "rgcsr_spmm",
-           "EllPlan", "make_ell_plan", "ell_spmv", "default_interpret",
+           "EllPlan", "make_ell_plan", "ell_spmv", "hybrid_spmv",
+           "default_interpret",
            "PlanCache", "PLAN_CACHE", "get_plan",
            "ShardedRgCSRPlan", "make_sharded_plan", "get_sharded_plan",
            "sharded_rgcsr_spmv", "sharded_rgcsr_spmm",
-           "sharded_plan_cache_stats",
-           "plan_from_params", "warm_plans_from_params",
-           "DEFAULT_X_TILE_ELEMS"]
+           "sharded_plan_cache_stats", "sharded_plan_placement",
+           "plan_from_params", "warm_plans_from_params"]
 
 
 def default_interpret() -> bool:
@@ -53,14 +54,6 @@ def default_interpret() -> bool:
 
 def _pad_to(x: int, m: int) -> int:
     return -(-x // m) * m
-
-
-# x elements staged into VMEM per SpMV grid step before column tiling kicks
-# in.  2^21 fp32 = 8 MiB — half the ~16 MiB/core VMEM, leaving room for the
-# (R, G) matrix tiles and the (1, G) accumulator.  Matrices at or below this
-# width keep the seed kernel's single unmasked whole-x stage; only wider
-# ones pay the masked multi-tile path.
-DEFAULT_X_TILE_ELEMS = 1 << 21
 
 
 @dataclasses.dataclass(frozen=True)
@@ -100,7 +93,7 @@ class RgCSRPlan:
 
     @property
     def num_steps(self) -> int:
-        """Grid steps the SpMV kernel launches (per x tile)."""
+        """Grid steps the SpMV kernel launches."""
         return int(self.step_group.shape[0])
 
     @property
@@ -394,16 +387,6 @@ def get_plan(m: RgCSR, *, chunks_per_step: int = 1, ordering: str = "block",
 # ---------------------------------------------------------------------------
 
 
-def _x_tile_for(n_pad_min: int, x_tile: Optional[int]) -> Tuple[int, int]:
-    """Resolve the x column-tile width and the final padded x length."""
-    if x_tile is None:
-        if n_pad_min <= DEFAULT_X_TILE_ELEMS:
-            return n_pad_min, n_pad_min          # single tile — seed behaviour
-        x_tile = DEFAULT_X_TILE_ELEMS
-    x_tile = _pad_to(x_tile, LANES)
-    return x_tile, _pad_to(n_pad_min, x_tile)
-
-
 @functools.partial(jax.jit, static_argnames=("n_rows", "has_spill"))
 def _adaptive_finish_spmv(y_flat, x, gather_idx, grouped_mask,
                           spill_values, spill_rows, spill_columns,
@@ -439,32 +422,23 @@ def _adaptive_finish_spmm(y2d, x, gather_idx, grouped_mask,
     return out
 
 
-def rgcsr_spmv(plan: RgCSRPlan, x, *, interpret: bool | None = None,
-               x_tile: int | None = None):
+def rgcsr_spmv(plan: RgCSRPlan, x, *, interpret: bool | None = None):
     """y = A @ x via the Pallas kernel. x: (n_cols,) -> y: (n_rows,).
 
-    ``x_tile`` bounds the x slice staged into VMEM per grid step; ``None``
-    stages x whole when it fits (``DEFAULT_X_TILE_ELEMS``) and tiles it
-    otherwise, so wide matrices degrade smoothly instead of exhausting VMEM.
-
     Adaptive plans return through the fused epilogue (inverse gather +
-    spill segment-sum); block plans slice the contiguous rows as before.
+    spill segment-sum); block plans slice the contiguous rows.
     """
     if interpret is None:
         interpret = default_interpret()
-    n_pad_min = _pad_to(max(plan.n_cols, 1), LANES)
-    xt, n_pad = _x_tile_for(n_pad_min, x_tile)
-    x_pad = jnp.zeros((1, n_pad), x.dtype).at[0, : plan.n_cols].set(x)
-    y = rgcsr_spmv_pallas(
+    x = jnp.asarray(x)
+    y_flat = rgcsr_spmv_pallas(
         plan.step_group, plan.step_first, plan.values2d, plan.columns2d,
-        x_pad, n_groups=plan.n_groups, group_size=plan.group_size,
-        chunks_per_step=plan.chunks_per_step, x_tile=xt,
-        interpret=interpret)
-    y_flat = y.reshape(-1)
+        x, n_groups=plan.n_groups, group_size=plan.group_size,
+        chunks_per_step=plan.chunks_per_step, interpret=interpret)
     if plan.ordering != "adaptive":
         return y_flat[: plan.n_rows]
     return _adaptive_finish_spmv(
-        y_flat, jnp.asarray(x), plan.gather_idx, plan.grouped_mask,
+        y_flat, x, plan.gather_idx, plan.grouped_mask,
         plan.spill_values, plan.spill_rows, plan.spill_columns,
         n_rows=plan.n_rows, has_spill=plan.n_spilled_elements > 0)
 
@@ -474,19 +448,16 @@ def rgcsr_spmm(plan: RgCSRPlan, x, *, d_tile: int = LANES,
     """Y = A @ X via the Pallas kernel. X: (n_cols, d) -> Y: (n_rows, d)."""
     if interpret is None:
         interpret = default_interpret()
-    n, d = x.shape
-    n_pad = _pad_to(max(n, 1), SUBLANES)
-    d_pad = _pad_to(max(d, 1), d_tile)
-    x_pad = jnp.zeros((n_pad, d_pad), x.dtype).at[:n, :d].set(x)
+    x = jnp.asarray(x)
     y = rgcsr_spmm_pallas(
         plan.step_group, plan.step_first, plan.values2d, plan.columns2d,
-        x_pad, n_groups=plan.n_groups, group_size=plan.group_size,
+        x, n_groups=plan.n_groups, group_size=plan.group_size,
         d_tile=d_tile, chunks_per_step=plan.chunks_per_step,
         interpret=interpret)
     if plan.ordering != "adaptive":
-        return y[: plan.n_rows, :d]
+        return y[: plan.n_rows]
     return _adaptive_finish_spmm(
-        y, jnp.asarray(x), plan.gather_idx, plan.grouped_mask,
+        y, x, plan.gather_idx, plan.grouped_mask,
         plan.spill_values, plan.spill_rows, plan.spill_columns,
         n_rows=plan.n_rows, has_spill=plan.n_spilled_elements > 0)
 
@@ -779,17 +750,19 @@ def make_sharded_plan(sm: ShardedRgCSR, *, chunks_per_step: int = 1,
         sources = []
         for d, shard in enumerate(sm.shards):
             lo, hi = d * cstride, min((d + 1) * cstride, n_cols)
-            # CSR-based split: only the (rps, cols_per_shard) local block is
-            # ever densified (for RgCSR.from_dense); the remote entries stay
-            # as index triplets — no full-width densification
+            # CSR-based split, no densification: local entries (columns
+            # remapped into this shard's slice) build the grouped storage,
+            # remote entries stay as index triplets for the exchange tail
             csr_v, csr_c, row_ptr = shard.to_csr_arrays()
             csr_r = np.repeat(np.arange(sm.rows_per_shard, dtype=np.int32),
                               np.diff(row_ptr))
             is_local = (csr_c >= lo) & (csr_c < hi)
-            local = np.zeros((sm.rows_per_shard, cstride), csr_v.dtype)
-            local[csr_r[is_local], csr_c[is_local] - lo] = csr_v[is_local]
-            sources.append(RgCSR.from_dense(local, group_size=g,
-                                            slot_pad=sm.slot_pad))
+            local_ptr = np.concatenate([[0], np.cumsum(np.bincount(
+                csr_r[is_local], minlength=sm.rows_per_shard))])
+            sources.append(RgCSR.from_csr(
+                csr_v[is_local], csr_c[is_local] - lo, local_ptr,
+                (sm.rows_per_shard, cstride), group_size=g,
+                slot_pad=sm.slot_pad))
             rc = csr_c[~is_local].astype(np.int64)
             remotes.append(np.unique(rc))
             rem_tails.append((csr_v[~is_local], csr_r[~is_local], rc))
@@ -869,31 +842,32 @@ def make_sharded_plan(sm: ShardedRgCSR, *, chunks_per_step: int = 1,
                 gidx[d] = np.arange(sm.rows_per_shard, dtype=np.int32)
                 gmask[d] = True
     split = x_mode == "split"
+    # host numpy on purpose: the plan is mesh-agnostic, and the run path
+    # places each stacked array once per mesh, sharded on its device axis
+    # (``_sharded_exec``) — never whole on one device
     return ShardedRgCSRPlan(
-        values3d=jnp.asarray(vals),
-        columns3d=jnp.asarray(cols),
-        step_group2d=jnp.asarray(sg2),
-        step_first2d=jnp.asarray(sf2),
+        values3d=vals,
+        columns3d=cols,
+        step_group2d=sg2,
+        step_first2d=sf2,
         n_rows=n_rows, n_cols=n_cols, n_shards=d_sh,
         rows_per_shard=sm.rows_per_shard, cols_per_shard=cstride,
         n_groups=n_groups, group_size=g, chunks_per_step=kernel_cps,
         ordering="adaptive" if any_adaptive else "block",
         spill_threshold=int(spill_threshold),
         x_mode=x_mode, nnz=sm.nnz, shard_configs=cfgs,
-        # host numpy on purpose: the run path consumes send_idx/rem_* only;
-        # remote_cols feeds host-side stats/tests — no device upload needed
         remote_cols=remote_cols if split else None,
-        send_idx=jnp.asarray(send_idx) if split and e_max else None,
+        send_idx=send_idx if split and e_max else None,
         edge_counts=edge_counts,
         e_max=e_max,
-        rem_values=jnp.asarray(rm_v) if split and e_max else None,
-        rem_rows=jnp.asarray(rm_r) if split and e_max else None,
-        rem_xidx=jnp.asarray(rm_x) if split and e_max else None,
-        gather_idx=jnp.asarray(gidx) if any_adaptive else None,
-        grouped_mask=jnp.asarray(gmask) if any_adaptive else None,
-        spill_values=jnp.asarray(sp_v) if any_adaptive else None,
-        spill_rows=jnp.asarray(sp_r) if any_adaptive else None,
-        spill_columns=jnp.asarray(sp_c) if any_adaptive else None,
+        rem_values=rm_v if split and e_max else None,
+        rem_rows=rm_r if split and e_max else None,
+        rem_xidx=rm_x if split and e_max else None,
+        gather_idx=gidx if any_adaptive else None,
+        grouped_mask=gmask if any_adaptive else None,
+        spill_values=sp_v if any_adaptive else None,
+        spill_rows=sp_r if any_adaptive else None,
+        spill_columns=sp_c if any_adaptive else None,
         shard_stored_slots=tuple(p.stored_slots for p in plans),
         shard_num_steps=tuple(len(sg) for sg, _ in tables),
         shard_remote_cols=tuple(len(r) for r in remotes) if remotes
@@ -967,8 +941,9 @@ def sharded_plan_cache_stats() -> Dict[str, int]:
                 "entries": len(_SHARDED_PLANS)}
 
 
-# memo of jitted shard_map executables per (plan, mesh, axis, kind) — the
-# shard_map wrapper must be a stable callable for jax's jit cache to hit
+# memo of (jitted shard_map executable, plan arrays placed on the mesh) per
+# (plan, mesh, axis, kind) — the shard_map wrapper must be a stable
+# callable for jax's jit cache to hit, and the placement is done once
 _SHARDED_EXEC: "collections.OrderedDict[tuple, Any]" = \
     collections.OrderedDict()
 _SHARDED_EXEC_MAX = 32
@@ -1042,23 +1017,14 @@ def _build_sharded_exec(plan: ShardedRgCSRPlan, kind: str, mesh, axis: str,
             recv_flat = recv.reshape((recv_width,) + x_in.shape[1:])
         # split mode: grouped storage is local-column-only, so the kernel's
         # x working set is exactly this device's slice (cols_per_shard)
-        x_use = x_in
         if kind == "spmv":
-            n_eff = x_use.shape[0]
-            # same VMEM-bounded column tiling as the single-device wrapper:
-            # single tile while x fits, masked multi-tile beyond
-            xt, n_pad = _x_tile_for(_pad_to(max(n_eff, 1), LANES), None)
-            x_pad = jnp.zeros((1, n_pad), x_use.dtype).at[0, :n_eff].set(
-                x_use)
-            y = rgcsr_spmv_pallas(
-                sg, sf, vals, cols, x_pad, n_groups=n_groups,
-                group_size=group_size,
-                chunks_per_step=kernel_cps, x_tile=xt,
+            y_flat = rgcsr_spmv_pallas(
+                sg, sf, vals, cols, x_in, n_groups=n_groups,
+                group_size=group_size, chunks_per_step=kernel_cps,
                 interpret=interpret)
-            y_flat = y.reshape(-1)
             if adaptive:
                 y_loc = _adaptive_finish_spmv(
-                    y_flat, x_use, gi, gm, sv, sr, sc, n_rows=rps,
+                    y_flat, x_in, gi, gm, sv, sr, sc, n_rows=rps,
                     has_spill=has_spill)
             else:
                 y_loc = y_flat[:rps]
@@ -1068,21 +1034,16 @@ def _build_sharded_exec(plan: ShardedRgCSRPlan, kind: str, mesh, axis: str,
             prods = rm_v * jnp.take(recv_flat, rm_x, axis=0)
             return y_loc + jax.ops.segment_sum(prods, rm_r,
                                                num_segments=rps)
-        n_eff, d = x_use.shape
-        n_pad = _pad_to(max(n_eff, 1), SUBLANES)
-        d_pad = _pad_to(max(d, 1), d_tile)
-        x_pad = jnp.zeros((n_pad, d_pad), x_use.dtype).at[
-            :n_eff, :d].set(x_use)
         y = rgcsr_spmm_pallas(
-            sg, sf, vals, cols, x_pad, n_groups=n_groups,
+            sg, sf, vals, cols, x_in, n_groups=n_groups,
             group_size=group_size, d_tile=d_tile,
             chunks_per_step=kernel_cps, interpret=interpret)
         if adaptive:
             y_loc = _adaptive_finish_spmm(
-                y, x_use, gi, gm, sv, sr, sc, n_rows=rps,
+                y, x_in, gi, gm, sv, sr, sc, n_rows=rps,
                 has_spill=has_spill)
         else:
-            y_loc = y[:rps, :d]
+            y_loc = y[:rps]
         if recv_flat is None:
             return y_loc
         prods = jnp.take(recv_flat, rm_x, axis=0) * rm_v[:, None]
@@ -1096,9 +1057,10 @@ def _build_sharded_exec(plan: ShardedRgCSRPlan, kind: str, mesh, axis: str,
     else:
         in_specs.append(P(axis, None) if split else P(None, None))
         out_spec = P(axis, None)
-    return jax.jit(shard_map(per_shard, mesh=mesh,
-                             in_specs=tuple(in_specs), out_specs=out_spec,
-                             check_rep=False))
+    fn = jax.jit(shard_map(per_shard, mesh=mesh,
+                           in_specs=tuple(in_specs), out_specs=out_spec,
+                           check_rep=False))
+    return fn, in_specs[:-1]
 
 
 # mesh-signature memo: a Mesh's topology is immutable, so the O(n_devices)
@@ -1124,6 +1086,7 @@ def _mesh_signature(mesh) -> tuple:
 
 def _sharded_exec(plan: ShardedRgCSRPlan, kind: str, mesh, axis: str,
                   interpret: bool, d_tile: int = LANES):
+    from jax.sharding import NamedSharding
     if axis not in mesh.axis_names:
         raise ValueError(f"mesh has no axis {axis!r}: {mesh.axis_names}")
     if mesh.shape[axis] != plan.n_shards:
@@ -1132,20 +1095,32 @@ def _sharded_exec(plan: ShardedRgCSRPlan, kind: str, mesh, axis: str,
             f"{axis!r} has {mesh.shape[axis]} devices")
     key = (id(plan), kind, _mesh_signature(mesh), axis, interpret, d_tile)
     with _SHARDED_LOCK:
-        fn = _SHARDED_EXEC.get(key)
-        if fn is not None:
+        entry = _SHARDED_EXEC.get(key)
+        if entry is not None:
             _SHARDED_EXEC.move_to_end(key)
-            return fn
-    fn = _build_sharded_exec(plan, kind, mesh, axis, interpret, d_tile)
+            return entry
+    fn, specs = _build_sharded_exec(plan, kind, mesh, axis, interpret,
+                                    d_tile)
+    # each stacked plan array lives sharded on its device axis: device d
+    # holds only its own shard, placed once here and reused every call
+    args, _ = _sharded_args(plan)
+    entry = (fn, [jax.device_put(a, NamedSharding(mesh, spec))
+                  for a, spec in zip(args, specs)])
     with _SHARDED_LOCK:
         if key not in _SHARDED_EXEC:
-            _SHARDED_EXEC[key] = fn
+            _SHARDED_EXEC[key] = entry
             weakref.finalize(plan, _evict_sharded_exec, id(plan))
             while len(_SHARDED_EXEC) > _SHARDED_EXEC_MAX:
                 _SHARDED_EXEC.popitem(last=False)
         else:
-            fn = _SHARDED_EXEC[key]
-    return fn
+            entry = _SHARDED_EXEC[key]
+    return entry
+
+
+def sharded_plan_placement(plan: ShardedRgCSRPlan, *, mesh, axis: str):
+    """The plan's stacked arrays as the SpMV run path placed them on
+    ``mesh``: each is sharded on its leading (device) axis over ``axis``."""
+    return _sharded_exec(plan, "spmv", mesh, axis, default_interpret())[1]
 
 
 def _evict_sharded_exec(pid: int) -> None:
@@ -1165,8 +1140,7 @@ def sharded_rgcsr_spmv(plan: ShardedRgCSRPlan, x, *, mesh, axis: str,
     """
     if interpret is None:
         interpret = default_interpret()
-    fn = _sharded_exec(plan, "spmv", mesh, axis, interpret)
-    args, _ = _sharded_args(plan)
+    fn, args = _sharded_exec(plan, "spmv", mesh, axis, interpret)
     x = jnp.asarray(x)
     if plan.x_mode == "split":
         xw = plan.n_shards * plan.cols_per_shard
@@ -1180,8 +1154,7 @@ def sharded_rgcsr_spmm(plan: ShardedRgCSRPlan, x, *, mesh, axis: str,
     """Y = A @ X over a 1-D mesh axis (X dense (n_cols, d)) -> (n_rows, d)."""
     if interpret is None:
         interpret = default_interpret()
-    fn = _sharded_exec(plan, "spmm", mesh, axis, interpret, d_tile)
-    args, _ = _sharded_args(plan)
+    fn, args = _sharded_exec(plan, "spmm", mesh, axis, interpret, d_tile)
     x = jnp.asarray(x)
     if plan.x_mode == "split":
         xw = plan.n_shards * plan.cols_per_shard
@@ -1307,9 +1280,13 @@ class EllPlan:
     n_cols: int
 
 
-def make_ell_plan(m: ELLPACK) -> EllPlan:
-    vals = np.asarray(m.values)
-    cols = np.asarray(m.columns).astype(np.int32)
+def make_ell_plan(m) -> EllPlan:
+    """Kernel layout of an ELLPACK matrix or of a Hybrid's ELL part."""
+    if isinstance(m, HybridEllCoo):
+        vals, cols = np.asarray(m.ell_values), np.asarray(m.ell_columns)
+    else:
+        vals, cols = np.asarray(m.values), np.asarray(m.columns)
+    cols = cols.astype(np.int32)
     k, n = vals.shape
     k_pad, n_pad = _pad_to(k, SUBLANES), _pad_to(n, LANES)
     vp = np.zeros((k_pad, n_pad), vals.dtype)
@@ -1323,8 +1300,17 @@ def make_ell_plan(m: ELLPACK) -> EllPlan:
 def ell_spmv(plan: EllPlan, x, *, interpret: bool | None = None):
     if interpret is None:
         interpret = default_interpret()
-    n_pad = _pad_to(max(plan.n_cols, 1), LANES)
-    x_pad = jnp.zeros((1, n_pad), x.dtype).at[0, : plan.n_cols].set(x)
-    y = ell_spmv_pallas(plan.values2d, plan.columns2d, x_pad,
+    y = ell_spmv_pallas(plan.values2d, plan.columns2d, jnp.asarray(x),
                         interpret=interpret)
-    return y[0, : plan.n_rows]
+    return y[: plan.n_rows]
+
+
+def hybrid_spmv(m, x, *, interpret: bool | None = None):
+    """y = A @ x for ELLPACK, or Hybrid (ELL kernel + COO segment-sum)."""
+    x = jnp.asarray(x)
+    y = ell_spmv(make_ell_plan(m), x, interpret=interpret)
+    if isinstance(m, HybridEllCoo) and m.coo_values.shape[0]:
+        y = y + jax.ops.segment_sum(
+            m.coo_values * jnp.take(x, m.coo_columns, axis=0), m.coo_rows,
+            num_segments=m.shape[0])
+    return y

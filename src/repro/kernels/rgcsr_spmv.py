@@ -20,11 +20,11 @@ vs ELLPACK) is handled with a **step table** built at plan time
 * grid step ``s`` covers slot rows ``[R·s, R·(s+1))`` with
   ``R = 8 · chunks_per_step`` and belongs to exactly one group
   ``step_group[s]`` (K_g % R == 0 guarantees no step straddles a group);
-* the grid is ``(num_steps, x_tiles)`` — *no* grid step is spent on
-  nonexistent slots of short groups.  This realizes the paper's "skip
-  meaningless arithmetic via rowLengths" at DMA granularity, which is what
-  matters on a memory-bound op (the VPU flops on padding are free; the HBM
-  bytes and grid steps are not).
+* the grid is ``(num_steps,)`` — *no* grid step is spent on nonexistent
+  slots of short groups.  This realizes the paper's "skip meaningless
+  arithmetic via rowLengths" at DMA granularity, which is what matters on a
+  memory-bound op (the VPU flops on padding are free; the HBM bytes and
+  grid steps are not).
 
 **Chunk coarsening** (``chunks_per_step`` ∈ {1, 2, 4, 8}): one grid step
 processes ``chunks_per_step`` 8-slot chunks of the same group, accumulating
@@ -34,21 +34,20 @@ the cost is padding short groups up to the coarsened tile (masked by exact
 zeros placed at plan time via the chunk table).  The autotuner
 (:mod:`repro.kernels.autotune`) measures this trade per matrix.
 
-**Column-tiled x staging**: ``x`` is staged into VMEM in ``(1, XT)`` tiles
-instead of whole (the paper's texture-cache remedy, bounded): the inner grid
-dimension walks the tiles and per-element contributions outside the resident
-tile are masked.  With a single tile (``n_pad <= XT``) the kernel is
-bit-identical in structure to the uncoarsened seed kernel; with many tiles,
-matrices whose ``n_cols · itemsize`` exceeds the VMEM budget no longer fall
-off a cliff (previously: whole-``x`` staging failed or thrashed for
-``n ≳ 4M`` fp32 elements).  For distributed runs, additionally shard columns
-over the mesh (see repro.sharding).
+**The x gather runs in XLA, before the kernel.**  The TPU compiler has no
+general in-kernel gather (Mosaic accepts only 2-D gathers within a tile),
+so ``x[columns2d]`` is formed by one XLA gather into an ``(S, G)`` stream
+laid out exactly like the value tile, and the kernel multiplies the two
+``(R, G)`` tiles and reduces over slots.  x itself is never staged into
+VMEM, so its width is not bounded by VMEM.  The price is one extra
+``(S, G)`` stream of HBM bytes (written by the gather, read by the kernel).
 
 Scalar-prefetch carries ``step_group`` (output index map) and ``step_first``
-(accumulator init).  The same output block is revisited only by consecutive
-grid steps (steps of a group are contiguous, and all x-tiles of one step are
-consecutive inner iterations), which is the Pallas TPU requirement for
-read-modify-write output accumulation.
+(accumulator init).  The output is ``(n_groups, 1, G)`` float32 — a
+``(1, G)`` block whose last two dims equal the array's, which the TPU
+tiling rule accepts — and the same output block is revisited only by
+consecutive grid steps (steps of a group are contiguous), which is the
+Pallas TPU requirement for read-modify-write output accumulation.
 
 **Permuted row space** (adaptive plans, DESIGN.md §5): the kernel is
 deliberately agnostic to *which* rows a group holds — the step table is the
@@ -80,48 +79,30 @@ __all__ = ["rgcsr_spmv_kernel", "rgcsr_spmv_pallas",
 
 
 def rgcsr_spmv_kernel(step_group_ref, step_first_ref,
-                      values_ref, columns_ref, x_ref, y_ref,
-                      *, x_tiled: bool):
+                      values_ref, xg_ref, y_ref):
     """Kernel body.
 
-    Blocks: values/columns ``(R, G)`` with ``R = 8·chunks_per_step``;
-    x ``(1, XT)`` column tile; y ``(1, G)``.
-
-    ``x_tiled`` is static: with a single x tile the gather is unmasked
-    (identical arithmetic to the seed kernel); with several tiles each
-    element's contribution is masked to the resident tile.
+    Blocks: values and gathered x ``(R, G)`` with ``R = 8·chunks_per_step``;
+    y ``(1, G)`` float32 accumulator of the step's group.
     """
     s = pl.program_id(0)
-    t = pl.program_id(1)
 
-    @pl.when((step_first_ref[s] == 1) & (t == 0))
+    @pl.when(step_first_ref[s] == 1)
     def _init():
         y_ref[...] = jnp.zeros_like(y_ref)
 
-    vals = values_ref[...]                          # (R, G)
-    cols = columns_ref[...]                         # (R, G) int32
-    x = x_ref[0, :]                                 # (XT,)
-    if x_tiled:
-        xt = x_ref.shape[1]
-        local = cols - t * xt
-        in_tile = (local >= 0) & (local < xt)
-        safe = jnp.clip(local, 0, xt - 1)
-        gathered = jnp.take(x, safe.reshape(-1), axis=0).reshape(cols.shape)
-        prods = jnp.where(in_tile, vals * gathered, jnp.zeros_like(vals))
-    else:
-        gathered = jnp.take(x, cols.reshape(-1), axis=0).reshape(cols.shape)
-        prods = vals * gathered
+    prods = (values_ref[...].astype(jnp.float32)
+             * xg_ref[...].astype(jnp.float32))          # (R, G)
     y_ref[...] += jnp.sum(prods, axis=0, keepdims=True)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("n_groups", "group_size", "chunks_per_step", "x_tile",
+    static_argnames=("n_groups", "group_size", "chunks_per_step",
                      "interpret"))
-def rgcsr_spmv_pallas(step_group, step_first, values2d, columns2d, x_pad,
+def rgcsr_spmv_pallas(step_group, step_first, values2d, columns2d, x,
                       *, n_groups: int, group_size: int,
-                      chunks_per_step: int = 1, x_tile: int | None = None,
-                      interpret: bool = True):
+                      chunks_per_step: int = 1, interpret: bool = True):
     """Launch the RgCSR SpMV kernel.
 
     Args:
@@ -130,39 +111,34 @@ def rgcsr_spmv_pallas(step_group, step_first, values2d, columns2d, x_pad,
       values2d:     (S, G) slot-major values (S = total padded slots; every
                     group's slot count is a multiple of 8·chunks_per_step).
       columns2d:    (S, G) int32 column indices (ghost index 0 on padding).
-      x_pad:        (1, n_pad) the dense vector, padded to a multiple of
-                    ``x_tile`` (or of 128 when untiled).
+      x:            (n_cols,) the dense vector.
       n_groups, group_size, chunks_per_step: static layout parameters.
-      x_tile:       x column-tile width (multiple of 128 dividing n_pad);
-                    None stages x whole (seed behaviour).
       interpret:    run in interpret mode (CPU validation) or compile for TPU.
 
     Returns:
-      (n_groups, G) per-group result rows; caller reshapes/unpads.
+      (n_groups · G,) per-group result rows in the result dtype of
+      ``values2d`` and ``x``; the caller unpads.
     """
-    num_steps = step_group.shape[0]
     g = group_size
     rows_per_step = chunks_per_step * SUBLANES
-    n_pad = x_pad.shape[1]
-    xt = n_pad if x_tile is None else x_tile
-    if n_pad % xt:
-        raise ValueError(f"x_tile {xt} must divide padded x width {n_pad}")
-    n_x_tiles = n_pad // xt
+    out_dtype = jnp.result_type(values2d.dtype, x.dtype)
+    xg = jnp.take(x, columns2d, axis=0)                   # (S, G) XLA gather
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(num_steps, n_x_tiles),
+        grid=(step_group.shape[0],),
         in_specs=[
-            pl.BlockSpec((rows_per_step, g), lambda s, t, sg, sf: (s, 0)),
-            pl.BlockSpec((rows_per_step, g), lambda s, t, sg, sf: (s, 0)),
-            pl.BlockSpec((1, xt), lambda s, t, sg, sf: (0, t)),
+            pl.BlockSpec((rows_per_step, g), lambda s, sg, sf: (s, 0)),
+            pl.BlockSpec((rows_per_step, g), lambda s, sg, sf: (s, 0)),
         ],
-        out_specs=pl.BlockSpec((1, g), lambda s, t, sg, sf: (sg[s], 0)),
+        out_specs=pl.BlockSpec((None, 1, g),
+                               lambda s, sg, sf: (sg[s], 0, 0)),
     )
-    kernel = functools.partial(rgcsr_spmv_kernel, x_tiled=n_x_tiles > 1)
-    return pl.pallas_call(
-        kernel,
+    y = pl.pallas_call(
+        rgcsr_spmv_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_groups, g), values2d.dtype),
+        out_shape=jax.ShapeDtypeStruct((n_groups, 1, g), jnp.float32),
         interpret=interpret,
-    )(step_group, step_first, values2d, columns2d, x_pad)
+        name="rgcsr_spmv",
+    )(step_group, step_first, values2d, xg)
+    return y.reshape(-1).astype(out_dtype)

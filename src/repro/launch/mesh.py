@@ -27,7 +27,8 @@ class HW:
     HBM_BW = 819e9               # bytes/s per chip
     ICI_BW = 50e9                # bytes/s per link (~per axis direction)
     HBM_BYTES = 16 * 2 ** 30     # v5e HBM capacity
-    VMEM_BYTES = 128 * 2 ** 20
+    VMEM_BYTES = 128 * 2 ** 20   # physical; Mosaic's default scoped limit
+                                 # per kernel is 16 MiB (analyze.TPU_V5E)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -36,14 +37,23 @@ def make_production_mesh(*, multi_pod: bool = False):
     return make_mesh(shape, axes)
 
 
-def make_mesh(shape, axes):
-    """Build a mesh over the first prod(shape) available devices."""
+def make_mesh(shape, axes, devices=None):
+    """Build a mesh over the first prod(shape) of ``devices`` (default: all
+    available devices).
+
+    Every axis is ``AxisType.Auto``: the installed JAX makes
+    ``jax.make_mesh`` axes Explicit by default, and gathers on operands
+    sharded over Explicit axes (the embedding lookup, slicing a sharded
+    SpMV result) then refuse to infer their output sharding.
+    """
+    from jax.sharding import AxisType
     n = int(np.prod(shape))
-    devices = jax.devices()
+    devices = jax.devices() if devices is None else list(devices)
     if len(devices) < n:
         raise RuntimeError(
             f"mesh {shape} needs {n} devices but only {len(devices)} exist; "
-            f"the dry-run entry point must set "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
-            f"any jax import-time device initialization")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+            f"on the CPU backend set "
+            f"XLA_FLAGS=--xla_force_host_platform_device_count=<n> before "
+            f"jax initializes its devices")
+    return jax.make_mesh(shape, axes, devices=devices[:n],
+                         axis_types=(AxisType.Auto,) * len(axes))

@@ -157,11 +157,13 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     from repro.configs import get_config, get_smoke
+    from repro.launch.compile_cache import use_compile_cache
     from repro.serve import Engine, Request, Router, RouterConfig, \
         ServeConfig
     from repro.train.checkpoint import SnapshotManager, restore_snapshot
     from repro.train.fault import FaultConfig, FaultInjector, ProcessKilled
 
+    use_compile_cache()
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     scfg = ServeConfig(
         max_seq=args.max_seq, n_slots=args.slots, kv_layout=args.kv_layout,

@@ -43,11 +43,13 @@ def main(argv=None):
 
     from repro.configs import get_config, get_smoke
     from repro.configs.base import SparsityConfig
+    from repro.launch.compile_cache import use_compile_cache
     from repro.launch.mesh import make_mesh
     from repro.sharding import Partitioner
     from repro.train import TrainConfig, Trainer
     from repro.train.optimizer import OptimizerConfig
 
+    use_compile_cache()
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     if args.sparse_ffn:
         cfg = dataclasses.replace(cfg, sparsity=SparsityConfig(

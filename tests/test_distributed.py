@@ -28,10 +28,11 @@ def test_partitioner_rules_resolve():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax
+        from repro.launch.mesh import make_mesh
         from jax.sharding import PartitionSpec as P
         from repro.sharding import Partitioner
         from repro.models.spec import P as Spec
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         part = Partitioner(mesh, "train")
         # divisible dims shard; non-divisible fall back to replicated
         s = part._leaf_spec(Spec((16, 8), ("embed", "mlp")))
@@ -55,6 +56,7 @@ def test_train_step_compiles_on_mesh_and_runs():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import dataclasses, jax, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_smoke
         from repro.sharding import Partitioner
         from repro.launch.steps import make_train_step
@@ -62,7 +64,7 @@ def test_train_step_compiles_on_mesh_and_runs():
         from repro.models import LanguageModel
         from repro.train.data import DataConfig, make_batch
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = dataclasses.replace(
             get_smoke("granite-3-2b"), act_shard=True,
             attn_shard_mode="repeat", mesh_batch_axes=("data",),
@@ -93,10 +95,11 @@ def test_elastic_reshard_checkpoint():
         import os, tempfile
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.train.checkpoint import save, restore_sharded
-        mesh_a = jax.make_mesh((2, 4), ("data", "model"))
-        mesh_b = jax.make_mesh((1, 8), ("data", "model"))
+        mesh_a = make_mesh((2, 4), ("data", "model"))
+        mesh_b = make_mesh((1, 8), ("data", "model"))
         w = jax.device_put(np.arange(64, dtype=np.float32).reshape(8, 8),
                            NamedSharding(mesh_a, P("data", "model")))
         with tempfile.TemporaryDirectory() as d:

@@ -9,7 +9,7 @@ from repro.core import FORMATS, from_dense, spmm, spmv
 from repro.core.analyze import GTX280, peak_model_gflops, row_stats
 from repro.core.formats import RgCSR, _hybrid_split_k
 from repro.core.ordering import ORDERINGS, descending_ordering, permute_rows
-from repro.core.suite import generate, paper_twins
+from repro.core.suite import generate, paper_twins, small_corpus
 
 FMT_KWARGS = {
     "rgcsr": dict(group_size=32, slot_pad=4),
@@ -127,3 +127,108 @@ def test_suite_families_deterministic(family):
     b = generate(family, 64, seed=5)
     np.testing.assert_array_equal(a, b)
     assert (a != 0).sum() > 0
+
+
+# ------------------------------------------------ CSR construction path
+
+
+def _csr_of(dense):
+    rows, cols = np.nonzero(dense)
+    row_ptr = np.concatenate([[0], np.cumsum(
+        np.bincount(rows, minlength=dense.shape[0]))])
+    return dense[rows, cols], cols.astype(np.int32), row_ptr
+
+
+def _per_row_rgcsr(dense, g, slot_pad):
+    """Independent per-row reference for the grouped layout: row r's
+    nonzeros fill slots 0..len-1 of lane r % g in its group's tile."""
+    n = dense.shape[0]
+    n_groups = max(1, -(-n // g))
+    lens = (dense != 0).sum(axis=1)
+    slots = []
+    for gi in range(n_groups):
+        k = int(lens[gi * g:(gi + 1) * g].max()) if gi * g < n else 0
+        slots.append(-(-max(k, 1) // slot_pad) * slot_pad)
+    values = [np.zeros((k, g), dense.dtype) for k in slots]
+    columns = [np.zeros((k, g), np.int32) for k in slots]
+    for r in range(n):
+        cols_r = np.nonzero(dense[r])[0]
+        values[r // g][: len(cols_r), r % g] = dense[r, cols_r]
+        columns[r // g][: len(cols_r), r % g] = cols_r
+    return (np.concatenate([v.reshape(-1) for v in values]),
+            np.concatenate([c.reshape(-1) for c in columns]),
+            np.asarray(slots, np.int32), lens.astype(np.int32))
+
+
+@pytest.mark.parametrize("spec", small_corpus(), ids=lambda s: s.name)
+def test_rgcsr_from_csr_equals_from_dense(spec):
+    dense = spec.build()
+    a = RgCSR.from_dense(dense)
+    b = RgCSR.from_csr(*_csr_of(dense), dense.shape)
+    for name in RgCSR._array_fields:
+        np.testing.assert_array_equal(np.asarray(getattr(a, name)),
+                                      np.asarray(getattr(b, name)), name)
+    values, columns, slots, lens = _per_row_rgcsr(dense, 128, 8)
+    np.testing.assert_array_equal(np.asarray(b.values), values)
+    np.testing.assert_array_equal(np.asarray(b.columns), columns)
+    np.testing.assert_array_equal(np.asarray(b.slots_per_group), slots)
+    np.testing.assert_array_equal(np.asarray(b.row_lengths), lens)
+    np.testing.assert_array_equal(b.to_dense(), dense)
+
+
+@pytest.mark.parametrize("g,slot_pad", [(32, 4), (4, 1), (256, 8)])
+def test_rgcsr_from_csr_group_sizes(g, slot_pad):
+    dense = _rand_sparse(71, 150, 90, 0.1)
+    dense[7] = 0.0                                  # an empty row
+    b = RgCSR.from_csr(*_csr_of(dense), dense.shape, group_size=g,
+                       slot_pad=slot_pad)
+    values, columns, slots, _ = _per_row_rgcsr(dense, g, slot_pad)
+    np.testing.assert_array_equal(np.asarray(b.values), values)
+    np.testing.assert_array_equal(np.asarray(b.columns), columns)
+    np.testing.assert_array_equal(np.asarray(b.slots_per_group), slots)
+    x = np.random.default_rng(72).standard_normal(90).astype(np.float32)
+    np.testing.assert_allclose(np.asarray(spmv(b, jnp.asarray(x))),
+                               dense @ x, rtol=1e-4, atol=1e-4)
+
+
+def test_hybrid_and_sharded_from_csr_match_dense():
+    from repro.core.formats import HybridEllCoo, ShardedRgCSR
+    dense = generate("circuit", 300, seed=3)
+    csr = _csr_of(dense)
+    h = HybridEllCoo.from_csr(*csr, dense.shape)
+    k1 = h.k1
+    assert k1 == _hybrid_split_k((dense != 0).sum(axis=1))
+    # per-row reference: the first k1 nonzeros of row i fill ELL column i,
+    # the rest go to the COO tail in row-major order
+    ell = np.zeros((k1, dense.shape[0]), np.float32)
+    coo = []
+    for i in range(dense.shape[0]):
+        cols_i = np.nonzero(dense[i])[0]
+        ell[: len(cols_i[:k1]), i] = dense[i, cols_i[:k1]]
+        coo += [(i, c, dense[i, c]) for c in cols_i[k1:]]
+    assert len(coo) > 0                             # dense rows spill
+    np.testing.assert_array_equal(np.asarray(h.ell_values), ell)
+    np.testing.assert_array_equal(
+        np.stack([np.asarray(h.coo_rows), np.asarray(h.coo_columns)], 1),
+        np.array([(r, c) for r, c, _ in coo]))
+    np.testing.assert_array_equal(np.asarray(h.coo_values),
+                                  np.array([v for _, _, v in coo]))
+    np.testing.assert_array_equal(h.to_dense(), dense)
+    sm = ShardedRgCSR.from_csr(*csr, dense.shape, n_shards=8)
+    assert sm.rows_per_shard == 38
+    np.testing.assert_array_equal(sm.to_dense(), dense)
+
+
+def test_stencil27_csr_structure():
+    from repro.core.suite import stencil27_csr
+    values, columns, row_ptr, shape = stencil27_csr((3, 4, 5), seed=1)
+    assert shape == (60, 60)
+    lens = np.diff(row_ptr)
+    assert lens.max() == 27 and lens.min() == 8     # interior and corners
+    assert len(values) == (3 * 3 - 2) * (3 * 4 - 2) * (3 * 5 - 2)
+    for r in range(shape[0]):                       # ascending, in-grid
+        cols = columns[row_ptr[r]: row_ptr[r + 1]]
+        assert (np.diff(cols) > 0).all() and r in cols
+    a = RgCSR.from_csr(values, columns, row_ptr, shape)
+    dense = a.to_dense()
+    np.testing.assert_array_equal(dense != 0, dense.T != 0)   # symmetric

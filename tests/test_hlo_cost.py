@@ -70,9 +70,10 @@ def test_collectives_counted_inside_loops():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.launch.hlo_cost import analyze_hlo
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         def f(ws, x):
             def body(c, w):
                 return c @ w, None

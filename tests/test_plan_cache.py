@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import from_dense
-from repro.core.spmv import spmv
+from repro.core.spmv import spmm, spmv
 from repro.core.suite import generate
 from repro.kernels import autotune
 from repro.kernels.ops import (PLAN_CACHE, PlanCache, get_plan, make_plan,
@@ -102,15 +102,17 @@ def test_coarsened_matches_oracle_on_corpus(family, cps):
 
 
 def test_spmv_x_tiling_matches_untiled():
+    """x is gathered by XLA before the kernel and never staged into VMEM,
+    so a matrix far wider than one 128-lane tile needs no x tiling: the
+    kernel result equals the oracle and the dense product."""
     a = _rand(5, 130, 1000, 0.02)
     mat = from_dense(a, "rgcsr", group_size=128)
     plan = make_plan(mat, chunks_per_step=2)
     x = np.random.default_rng(6).standard_normal(1000).astype(np.float32)
-    whole = np.asarray(rgcsr_spmv(plan, jnp.asarray(x), interpret=True))
-    tiled = np.asarray(rgcsr_spmv(plan, jnp.asarray(x), interpret=True,
-                                  x_tile=128))
-    np.testing.assert_allclose(tiled, whole, rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(tiled, a @ x, rtol=1e-4, atol=1e-4)
+    got = np.asarray(rgcsr_spmv(plan, jnp.asarray(x), interpret=True))
+    ref = np.asarray(spmv(mat, jnp.asarray(x), impl="ref"))
+    np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(got, a @ x, rtol=1e-4, atol=1e-4)
 
 
 @pytest.mark.parametrize("cps", (1, 4))
@@ -300,3 +302,70 @@ def test_autotune_restricted_candidates_not_shadowed():
     res = autotune.autotune_spmv(a, repeats=1, candidates=cands)
     assert not res.from_memo
     assert res.config in cands
+
+
+# ------------------------------------------- explicit kernel, no fallback
+
+
+def test_explicit_kernel_raises_instead_of_oracle():
+    """impl='kernel' never answers from the oracle: a matrix traced under
+    jit, or a format with no kernel, raises."""
+    import jax
+    mat = from_dense(_rand(40, 96, 96, 0.08), "rgcsr", group_size=128)
+    x = jnp.asarray(np.random.default_rng(41).standard_normal(96)
+                    .astype(np.float32))
+    with pytest.raises(ValueError, match="concrete matrix"):
+        jax.jit(lambda m, v: spmv(m, v, impl="kernel"))(mat, x)
+    with pytest.raises(ValueError, match="no Pallas kernel"):
+        spmv(from_dense(_rand(42, 32, 32, 0.1), "csr"), x[:32],
+             impl="kernel")
+    with pytest.raises(ValueError, match="no Pallas kernel"):
+        spmm(from_dense(_rand(43, 32, 32, 0.1), "hybrid"),
+             jnp.zeros((32, 4)), impl="kernel")
+    # auto under tracing stays on the oracle, as documented
+    got = jax.jit(lambda m, v: spmv(m, v, impl="auto"))(mat, x)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(mat.to_dense())
+                               @ np.asarray(x), rtol=1e-4, atol=1e-4)
+
+
+def test_explicit_kernel_under_jit_with_closed_over_matrix():
+    import jax
+    a = _rand(44, 200, 150, 0.05)
+    mat = from_dense(a, "rgcsr", group_size=128)
+    x = np.random.default_rng(45).standard_normal(150).astype(np.float32)
+    got = jax.jit(lambda v: spmv(mat, v, impl="kernel"))(jnp.asarray(x))
+    np.testing.assert_allclose(np.asarray(got), a @ x, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("fmt", ["ellpack", "hybrid"])
+def test_ell_kernel_formats_match_oracle(fmt):
+    a = generate("circuit", 256, seed=5)            # Hybrid spills to COO
+    mat = from_dense(a, fmt)
+    x = np.random.default_rng(46).standard_normal(256).astype(np.float32)
+    got = np.asarray(spmv(mat, jnp.asarray(x), impl="kernel"))
+    ref = np.asarray(spmv(mat, jnp.asarray(x), impl="ref"))
+    np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(got, a @ x, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("cps", CPS_ALL)
+def test_bf16_kernels_accumulate_in_fp32(cps):
+    """bf16 values and x: the kernels accumulate in fp32 and round once, so
+    the result is the fp32 product of the bf16-rounded inputs to within one
+    bf16 rounding."""
+    a = _rand(47, 150, 140, 0.3).astype(jnp.bfloat16)
+    mat = from_dense(a, "rgcsr", group_size=128)
+    plan = make_plan(mat, chunks_per_step=cps)
+    x = np.random.default_rng(48).standard_normal(140).astype(jnp.bfloat16)
+    X = np.random.default_rng(49).standard_normal((140, 5)).astype(
+        jnp.bfloat16)
+    a32 = a.astype(np.float32)
+    y = rgcsr_spmv(plan, jnp.asarray(x), interpret=True)
+    Y = rgcsr_spmm(plan, jnp.asarray(X), interpret=True)
+    assert y.dtype == jnp.bfloat16 and Y.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(y, np.float32),
+                               a32 @ x.astype(np.float32), rtol=1e-2,
+                               atol=1e-2)
+    np.testing.assert_allclose(np.asarray(Y, np.float32),
+                               a32 @ X.astype(np.float32), rtol=1e-2,
+                               atol=1e-2)

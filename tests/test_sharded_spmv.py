@@ -31,6 +31,7 @@ from repro.core.formats import ShardedRgCSR
 from repro.core.spmv import spmv
 from repro.kernels import ops as kops
 from repro.kernels.rgcsr_spmv import rgcsr_spmv_pallas
+from repro.launch.mesh import make_mesh
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -150,12 +151,9 @@ def _emulate_shard(plan, d, x):
         x_use = x_glob[d * cstride: (d + 1) * cstride]
     else:
         x_use = x
-    n_pad = -(-len(x_use) // 128) * 128
-    x_pad = jnp.zeros((1, n_pad), jnp.float32).at[0, : len(x_use)].set(
-        jnp.asarray(x_use))
     y = rgcsr_spmv_pallas(
         plan.step_group2d[d], plan.step_first2d[d], plan.values3d[d],
-        plan.columns3d[d], x_pad, n_groups=plan.n_groups,
+        plan.columns3d[d], jnp.asarray(x_use), n_groups=plan.n_groups,
         group_size=plan.group_size, chunks_per_step=plan.chunks_per_step,
         interpret=True)
     y = np.asarray(y).reshape(-1)[: plan.rows_per_shard].copy()
@@ -234,7 +232,7 @@ def test_split_single_device_degrades_to_local_only():
     plan = kops.get_sharded_plan(sm, x_mode="split")
     assert plan.n_shards == 1 and not plan.has_exchange
     assert plan.shard_remote_cols == (0,)
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     x = np.random.default_rng(25).standard_normal(96).astype(np.float32)
     y = np.asarray(spmv(sm, jnp.asarray(x), mesh=mesh, mesh_axis="model",
                         x_mode="split"))
@@ -342,7 +340,7 @@ def test_engine_warm_sharded_replaces_rewarm_keeps_distinct(
     import jax
     from repro.configs import get_smoke
     from repro.serve import Engine, ServeConfig
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     eng = Engine(get_smoke("granite-3-2b"), ServeConfig(max_seq=32))
     a = _rand(40, 256, 256, 0.05)
     b = _rand(41, 256, 256, 0.05)      # same log2 signature bucket as a
@@ -362,7 +360,7 @@ def test_sharded_exec_memo_evicts_on_plan_gc():
     import jax
     sm = ShardedRgCSR.from_dense(_rand(30, 64, 64, 0.1), n_shards=1)
     plan = kops.make_sharded_plan(sm, x_mode="split")
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     kops._sharded_exec(plan, "spmv", mesh, "model", True)
     pid = id(plan)
     with kops._SHARDED_LOCK:
@@ -400,7 +398,7 @@ def test_sharded_spmv_requires_mesh():
 def test_partitioner_resolves_sparse_rows_axis():
     import jax
     from repro.sharding import Partitioner
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     for kind in ("train", "decode"):
         part = Partitioner(mesh, kind)
         assert part.spmv_shard_axis() == "model"
@@ -430,12 +428,13 @@ def test_sharded_spmv_matches_oracle_on_8_devices():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, numpy as np, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro.core.formats import RgCSR, ShardedRgCSR
         from repro.core.spmv import spmv, spmm
         from repro.core.suite import generate
         from repro.kernels import ops as kops
 
-        mesh = jax.make_mesh((8,), ("model",))
+        mesh = make_mesh((8,), ("model",))
         rng = np.random.default_rng(0)
 
         def check(a, **kw):
@@ -521,6 +520,7 @@ def test_sharded_engine_warmup_and_partitioner_routing_on_8_devices():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, numpy as np, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_smoke
         from repro.core.formats import ShardedRgCSR
         from repro.core.spmv import spmv
@@ -528,7 +528,7 @@ def test_sharded_engine_warmup_and_partitioner_routing_on_8_devices():
         from repro.serve import Engine, ServeConfig
         from repro.sharding import Partitioner
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         part = Partitioner(mesh, "decode")
         assert part.spmv_shard_axis() == "model"
         assert part.spmv_shard_count() == 4
@@ -554,7 +554,7 @@ def test_sharded_engine_warmup_and_partitioner_routing_on_8_devices():
 
         # re-warming on a RESIZED mesh must build a fresh stacked plan
         # (plan-cache keys carry the shard count), never reuse the stale one
-        mesh8 = jax.make_mesh((1, 8), ("data", "model"))
+        mesh8 = make_mesh((1, 8), ("data", "model"))
         eng.warm_spmv_plans(mats, repeats=1, mesh=mesh8, x_mode="split")
         assert eng.sharded_spmv_shard_stats[1]["n_shards"] == 8
         assert eng.plan_cache_stats()["sharded_plan_cache"]["entries"] >= 2
