@@ -1,0 +1,7 @@
+"""Make the benchmark's modules and the program importable in its tests."""
+import os
+import sys
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, BENCH)
+sys.path.insert(1, os.path.join(os.path.dirname(BENCH), "src"))
