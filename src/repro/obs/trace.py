@@ -11,21 +11,34 @@ ordering leaks.  Export to Chrome trace-event JSON lives in
 
 Tracks are ``(process, thread)`` string pairs: one process per replica
 (``replica0`` ...) plus ``router``, and within a replica one lane per
-slot (``slot0`` ...) plus ``session`` for engine-level work and
-``device`` for fused-loop dispatch marks.
+slot (``slot0`` ...) plus ``session`` for engine-level work.
 
 Every emission site goes through a tracer attribute that defaults to the
 module-level :data:`NOOP` (a :class:`NullTracer`), so the serving hot
-path pays one attribute load + truthiness check when tracing is off.
+path pays one attribute load + truthiness check (or, for :meth:`span`,
+one call returning a shared no-op context) when tracing is off.
+
+``Tracer(profiler=True)`` also forwards every :meth:`Tracer.span` to
+``jax.profiler`` as a ``TraceAnnotation`` named ``repro.serve.<name>``:
+inside a profiler capture the span then lies on the profiler's host
+plane, on the same clock as the device's ops.  Only ``span()`` forwards:
+``begin``/``end`` pairs and request lifelines need not nest LIFO on one
+thread, which annotations must.
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 from typing import Dict, List, Optional, Tuple
 
-__all__ = ["NullTracer", "Tracer", "NOOP"]
+__all__ = ["NullTracer", "Tracer", "NOOP", "PROFILER_PREFIX"]
 
 Track = Tuple[str, str]
+
+# name prefix of the profiler annotations Tracer.span emits
+PROFILER_PREFIX = "repro.serve."
+
+_NULL_SPAN = contextlib.nullcontext()
 
 
 class NullTracer:
@@ -43,6 +56,9 @@ class NullTracer:
 
     def end(self, name, track, **args):
         pass
+
+    def span(self, name, track, **args):
+        return _NULL_SPAN
 
     def instant(self, name, track, **args):
         pass
@@ -71,15 +87,23 @@ class Tracer(NullTracer):
     use async events keyed by a tracer-assigned uid (a simple counter,
     stamped onto the request as ``_trace_uid``) — never ``id(req)``,
     which would differ between runs and break byte-identical exports.
+
+    ``profiler=True`` makes each :meth:`span` also a
+    ``jax.profiler.TraceAnnotation`` (see the module docstring); ``jax``
+    is imported only then.
     """
 
     enabled = True
 
-    def __init__(self, clock=None):
+    def __init__(self, clock=None, profiler: bool = False):
         self.clock = clock if clock is not None else _default_clock
         self.events: List[Dict] = []
         self._uids = itertools.count(1)
         self._open_async: set = set()
+        self._annotation = None
+        if profiler:
+            import jax.profiler
+            self._annotation = jax.profiler.TraceAnnotation
 
     # -- core emitters ----------------------------------------------------
 
@@ -105,6 +129,31 @@ class Tracer(NullTracer):
 
     def end(self, name, track, **args):
         self._emit("E", name, track, args)
+
+    @contextlib.contextmanager
+    def span(self, name, track, **args):
+        """A duration span over a ``with`` block: ``B`` on entry, ``E`` on
+        exit, ``E`` with ``error=True`` when an exception passes through.
+        Spans nest in the call stack, so they close LIFO by construction.
+        The profiler annotation carries the bare name: keyword arguments
+        of a ``TraceAnnotation`` become part of its event name, so
+        ``args`` stay in the in-memory event."""
+        self.begin(name, track, **args)
+        ann = None
+        if self._annotation is not None:
+            ann = self._annotation(PROFILER_PREFIX + name)
+            ann.__enter__()
+        failed = True
+        try:
+            yield
+            failed = False
+        finally:
+            if ann is not None:
+                ann.__exit__(None, None, None)
+            if failed:
+                self.end(name, track, error=True)
+            else:
+                self.end(name, track)
 
     def instant(self, name, track, **args):
         """A point event (preemption, migration, quarantine, ...)."""

@@ -92,9 +92,8 @@ def build_fused_decode(model, cfg, on_dispatch=None):
     """Build the jitted fused chunk runner for one engine config.
 
     ``on_dispatch`` (optional) is called with the full output tuple after
-    every dispatch, *inside* the returned callable — the engine's trace
-    hook rides here so fused-dispatch marks survive the test/bench
-    harnesses that wrap ``engine._fused_decode`` from the outside.
+    every dispatch, *inside* the returned callable.  The engine passes
+    none: a hook that reads the output waits on the device.
 
     Returns ``fused(params, caches, cur_tok, remaining, active, key,
     n_steps) → (block, steps_ran, cur_tok, key, caches, logit_ok)`` where

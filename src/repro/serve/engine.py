@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
+import logging
 import time
 from collections import deque
 from typing import Dict, List, Optional
@@ -46,6 +47,8 @@ from repro.serve import device_loop, paging
 
 __all__ = ["ServeConfig", "Engine", "EngineSession", "Request",
            "request_to_state", "request_from_state"]
+
+log = logging.getLogger(__name__)
 
 
 def request_to_state(req: "Request", now: float) -> Dict:
@@ -219,8 +222,8 @@ class Engine:
         # the fused lax.while_loop chunk runner EngineSession dispatches
         self._decode = jax.jit(device_loop.make_decode_step(self.model),
                                donate_argnums=(1,))
-        self._fused_decode = device_loop.build_fused_decode(
-            self.model, serve_cfg, on_dispatch=self._on_fused_dispatch)
+        self._fused_decode = device_loop.build_fused_decode(self.model,
+                                                            serve_cfg)
         self._prefill = jax.jit(
             lambda p, b: self.model.prefill(p, b, self.cfg.max_seq),
             static_argnums=())
@@ -370,17 +373,6 @@ class Engine:
         self._key, sub = jax.random.split(self._key)
         return device_loop.sample_tokens(logits, sub, self.cfg.temperature,
                                          self.cfg.top_k)
-
-    def _on_fused_dispatch(self, out) -> None:
-        """Trace hook run INSIDE the fused-decode callable (see
-        ``device_loop.build_fused_decode``) — test/bench harnesses wrap
-        ``engine._fused_decode`` from the outside, so an emission there
-        would be lost under their wrappers.  Late-bound: attaching a
-        tracer after engine construction takes effect immediately."""
-        tr = self.tracer
-        if tr is not None and tr.enabled:
-            tr.instant("fused_dispatch", (self.trace_label, "device"),
-                       steps=int(out[1]))
 
     # ------------------------------------------------------------- one-shot
     def generate(self, prompts: np.ndarray, max_new_tokens: int = 32
@@ -601,6 +593,13 @@ class EngineSession:
                     "nonfinite_logits"):
             self.stats[key] = 0
         self.stats["frag_at_high_water"] = 0.0
+        # host seconds of the decode dispatches, split at the clock reads
+        # of _run_chunk: launching, waiting on the device, committing
+        for key in ("decode_enqueue_s", "decode_wait_s", "decode_commit_s"):
+            self.stats[key] = 0.0
+        # (decode step, enqueue_s, wait_s, commit_s) of each dispatch the
+        # watchdog flagged
+        self.straggler_log: List[tuple] = []
         self.hists = {name: self.metrics.histogram(name)
                       for name in ("queue_s", "prefill_s", "latency_s")}
         if self.alloc is not None and self.trace.enabled:
@@ -885,23 +884,24 @@ class EngineSession:
                      np.asarray(prefix, np.int32)])
                 site = self.prefill_count
                 self.prefill_count += 1
-                self.trace.begin("prefill", lane, tokens=len(tokens))
                 try:
-                    if self.injector is not None:
-                        self.injector.check(site, site="prefill")
-                    logits, slot_cache = self.engine._prefill(
-                        self.engine.params,
-                        {"tokens": jnp.asarray(tokens[None, :],
-                                               jnp.int32)})
-                    first = int(self.engine._sample(logits)[0])
+                    # through the first token's int(): the host waits
+                    # for the prefill here
+                    with self.trace.span("prefill", lane,
+                                         tokens=len(tokens)):
+                        if self.injector is not None:
+                            self.injector.check(site, site="prefill")
+                        logits, slot_cache = self.engine._prefill(
+                            self.engine.params,
+                            {"tokens": jnp.asarray(tokens[None, :],
+                                                   jnp.int32)})
+                        first = int(self.engine._sample(logits)[0])
                 except Exception as e:  # noqa: BLE001 — isolate request
                     if self.strict:
                         raise
-                    self.trace.end("prefill", lane, error=True)
                     self.trace.end("request", lane, status="failed")
                     self._finish_bad(req, "failed", repr(e))
                     continue
-                self.trace.end("prefill", lane)
                 if req.out is None:
                     req.out = []
                 req.out.append(first)
@@ -912,14 +912,13 @@ class EngineSession:
                     self.trace.end("request", lane, status="ok")
                     self._finish_ok(req)
                     continue
+                paged_args = ()
                 if self.paged:
                     alloc.admit(slot, length, worst)
+                    paged_args = (alloc.table, self.geom.page_size)
+                with self.trace.span("commit_prefill", lane):
                     self.caches = paging.commit_prefill(
-                        self.caches, slot_cache, slot, length, alloc.table,
-                        self.geom.page_size)
-                else:
-                    self.caches = paging.commit_prefill(
-                        self.caches, slot_cache, slot, length)
+                        self.caches, slot_cache, slot, length, *paged_args)
                 self.active[slot] = req
                 self.admit_seq[slot] = self.seq_counter
                 self.seq_counter += 1
@@ -1043,7 +1042,8 @@ class EngineSession:
                 # its victims at the head, so recovery re-prefills in
                 # this very iteration
                 self._verify_integrity()
-            self._admit()
+            with self.trace.span("admit", self.track):
+                self._admit()
             if all(a is None for a in self.active):
                 if self.queue:
                     continue     # heads were rejected/timed out — refill
@@ -1051,7 +1051,8 @@ class EngineSession:
             self._sweep_deadlines()
             chunk = min(max(1, cfg.decode_chunk), max_steps - ran)
             if self.paged:
-                chunk = self._ensure_pages(chunk)
+                with self.trace.span("ensure_pages", self.track):
+                    chunk = self._ensure_pages(chunk)
             self._record_live()  # chunk-boundary peak (pre-dispatch)
             if all(a is None for a in self.active):
                 continue         # deadline sweep / self-eviction emptied
@@ -1085,37 +1086,61 @@ class EngineSession:
                     if idx is not None:
                         self.caches = paging.corrupt_page(
                             self.caches, idx, nan=True)
-            if self.trace.enabled:
-                if self.paged:
-                    self.trace.counter("free_pages", self.track,
-                                       free=self.alloc.free_pages)
-                self.trace.begin("decode_chunk", self.track,
-                                 chunk=int(chunk),
-                                 active=self.num_active)
+            if self.trace.enabled and self.paged:
+                self.trace.counter("free_pages", self.track,
+                                   free=self.alloc.free_pages)
+            with self.trace.span("decode_chunk", self.track,
+                                 chunk=int(chunk), active=self.num_active):
+                ran += self._run_chunk(chunk)
+            if self.injector is not None and self.paged:
+                # silent corruption at rest: injected AFTER the boundary
+                # fingerprints, so the recorded crc reflects the clean
+                # contents and the next iteration's verify flags the page
+                idx = self.injector.take("page")
+                if idx is not None:
+                    self.caches = paging.corrupt_page(self.caches, idx)
+        return ran
+
+    def _run_chunk(self, chunk: int) -> int:
+        """One fused dispatch of up to ``chunk`` decode steps and the host
+        commit of its tokens; returns the decode steps committed.
+
+        Four clock reads split the dispatch's host time into enqueue
+        (building the inputs and launching ``_fused_decode``, which
+        returns before the device is done), wait (fetching the results:
+        the host blocks on the device) and commit (the row-by-row commit
+        below), summed into the ``decode_enqueue_s`` / ``decode_wait_s``
+        / ``decode_commit_s`` counters; a dispatch the watchdog flags
+        logs its split in ``straggler_log``."""
+        cfg = self.cfg
+        t0 = self.clock()
+        with self.trace.span("dispatch", self.track):
             rem_dev = jnp.asarray(
                 [self.remaining[s] if self.active[s] is not None else 0
                  for s in range(self.n)], jnp.int32)
             act_dev = jnp.asarray(
                 [a is not None for a in self.active], bool)
-            step_t0 = self.clock()
             block, steps_ran, tok, key, self.caches, logit_ok = \
                 self.engine._fused_decode(
                     self.engine.params, self.caches, self.cur_tok,
                     rem_dev, act_dev, self.engine._key,
                     jnp.asarray(chunk, jnp.int32))
-            steps = int(steps_ran)
-            self.cur_tok = tok
-            self.engine._key = key
-            block = np.asarray(jax.device_get(block))
-            ok_block = np.asarray(jax.device_get(logit_ok))
-            self.stats["decode_dispatches"] += 1
-            # normalize wall time by steps actually fused into this
-            # dispatch — a k-step chunk must not read as a k× straggler
-            if self.watchdog.observe(self.stats["decode_steps"],
-                                     (self.clock() - step_t0)
-                                     / max(steps, 1)):
-                self.trace.instant("straggler_flagged", self.track,
-                                   step=self.stats["decode_steps"])
+        t1 = self.clock()
+        with self.trace.span("fetch", self.track):
+            block, steps, ok_block = jax.device_get(
+                (block, steps_ran, logit_ok))
+        t2 = self.clock()
+        steps = int(steps)
+        block, ok_block = np.asarray(block), np.asarray(ok_block)
+        self.cur_tok = tok
+        self.engine._key = key
+        self.stats["decode_dispatches"] += 1
+        step0 = self.stats["decode_steps"]
+        # normalize wall time by steps actually fused into this
+        # dispatch — a k-step chunk must not read as a k× straggler
+        flagged = self.watchdog.observe(step0, (t2 - t0) / max(steps, 1))
+        ran = 0
+        with self.trace.span("commit_tokens", self.track, steps=steps):
             for i in range(steps):
                 if all(a is None for a in self.active):
                     break        # decode faults emptied the batch early
@@ -1162,14 +1187,18 @@ class EngineSession:
                             self.alloc.release(slot)
             if self.kv_integrity:
                 self._record_checksums()
-            self.trace.end("decode_chunk", self.track, steps=steps)
-            if self.injector is not None and self.paged:
-                # silent corruption at rest: injected AFTER the boundary
-                # fingerprints, so the recorded crc reflects the clean
-                # contents and the next iteration's verify flags the page
-                idx = self.injector.take("page")
-                if idx is not None:
-                    self.caches = paging.corrupt_page(self.caches, idx)
+        t3 = self.clock()
+        split = (t1 - t0, t2 - t1, t3 - t2)
+        for name, sec in zip(("decode_enqueue_s", "decode_wait_s",
+                             "decode_commit_s"), split):
+            self.stats[name] += sec
+        if flagged:
+            self.straggler_log.append((step0,) + split)
+            self.trace.instant("straggler_flagged", self.track, step=step0,
+                               enqueue_s=split[0], wait_s=split[1],
+                               commit_s=split[2])
+            log.warning("straggler: dispatch at step %d: enqueue %.3fs, "
+                        "wait %.3fs, commit %.3fs", step0, *split)
         return ran
 
     def drain(self) -> None:

@@ -415,7 +415,8 @@ SERVE_MERGE_SPEC: Dict[str, obs_metrics.MergeRule] = {
         "rejected", "failed", "timed_out", "decode_steps",
         "decode_dispatches", "admission_deferrals", "evictions",
         "pages_evicted", "double_release", "pages_quarantined",
-        "nonfinite_logits", "restores", "restore_recompute_tokens")},
+        "nonfinite_logits", "restores", "restore_recompute_tokens",
+        "decode_enqueue_s", "decode_wait_s", "decode_commit_s")},
     "straggler_decode_steps": obs_metrics.MergeRule(
         "sum", list_as="straggler_decode_steps_per_replica"),
     **{k: obs_metrics.MergeRule("first") for k in (

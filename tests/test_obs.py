@@ -180,7 +180,8 @@ def test_merge_stats_serve_spec_semantics():
     # every session counter the engine seeds has a rule (schema drift guard)
     for key in ("requests", "completed", "preemptions", "rejected",
                 "failed", "timed_out", "restores", "pages_quarantined",
-                "decode_steps", "request_timing"):
+                "decode_steps", "request_timing", "decode_enqueue_s",
+                "decode_wait_s", "decode_commit_s"):
         assert key in SERVE_MERGE_SPEC
 
 
@@ -222,6 +223,46 @@ def test_noop_tracer_records_nothing():
     NOOP.begin("x", ("a", "b"))
     NOOP.request_begin(req, ("a", "b"))
     assert NOOP.enabled is False and not hasattr(NOOP, "events")
+
+
+def test_null_tracer_span_records_nothing():
+    """Tracing off: every span site gets the one shared no-op context."""
+    ctx = NOOP.span("dispatch", ("replica0", "session"), chunk=8)
+    assert ctx is NOOP.span("fetch", ("replica0", "session"))
+    with ctx as entered:
+        assert entered is None
+    with pytest.raises(KeyError):
+        with NOOP.span("admit", ("replica0", "session")):
+            raise KeyError("passes through")
+    assert not hasattr(NOOP, "events")
+
+
+def test_tracer_span_nests_and_closes_on_exception():
+    clock = FakeClock()
+    tr = Tracer(clock=clock)
+    lane = ("replica0", "session")
+    with tr.span("decode_chunk", lane, chunk=8):
+        clock.advance(1.0)
+        with tr.span("dispatch", lane):
+            clock.advance(0.5)
+    with pytest.raises(RuntimeError):
+        with tr.span("admit", lane):
+            with tr.span("prefill", ("replica0", "slot0"), tokens=4):
+                clock.advance(0.25)
+                raise RuntimeError("prefill fault")
+    got = [(e["ph"], e["name"], e["ts"], e.get("args")) for e in tr.events]
+    assert got == [
+        ("B", "decode_chunk", 0, {"chunk": 8}),
+        ("B", "dispatch", 1_000_000, None),
+        ("E", "dispatch", 1_500_000, None),
+        ("E", "decode_chunk", 1_500_000, None),
+        ("B", "admit", 1_500_000, None),
+        ("B", "prefill", 1_500_000, {"tokens": 4}),
+        ("E", "prefill", 1_750_000, {"error": True}),
+        ("E", "admit", 1_750_000, {"error": True}),
+    ]
+    doc = json.loads(obs_export.export_chrome_trace(tr))
+    assert obs_export.validate_chrome_trace(doc) == []
 
 
 def test_request_lifeline_guards():
@@ -343,7 +384,156 @@ def test_engine_trace_deterministic_byte_identical():
     assert summ["spans"]["request"]["n"] == 3
     assert summ["spans"]["prefill"]["n"] == 3
     assert summ["spans"]["decode_chunk"]["n"] >= 1
-    assert summ["events"]["fused_dispatch"] >= 1
+    assert summ["spans"]["dispatch"]["n"] >= 1
+
+
+SESSION_SPANS = ("admit", "prefill", "commit_prefill", "ensure_pages",
+                 "decode_chunk", "dispatch", "fetch", "commit_tokens")
+
+
+def _span_tree(events):
+    """(name, parent name) of each ``Tracer.span`` in opening order: the
+    spans nest in the call stack whatever their track, so one stack over
+    all tracks rebuilds the tree (lane-long ``request`` spans are not
+    ``span()``s and are left out)."""
+    out, stack = [], []
+    for ev in events:
+        if ev["name"] not in SESSION_SPANS or ev["ph"] not in "BE":
+            continue
+        if ev["ph"] == "B":
+            out.append((ev["name"], stack[-1] if stack else None))
+            stack.append(ev["name"])
+        else:
+            assert stack.pop() == ev["name"]
+    assert not stack
+    return out
+
+
+def test_engine_session_spans_match_stats():
+    """A FakeClock serve exports byte-identical, valid traces whose layer
+    spans count what the stats count: one dispatch, fetch and
+    commit_tokens per fused dispatch, inside its decode_chunk; one
+    prefill per request slotted, inside an admit."""
+    eng1, t1 = _traced_serve()
+    eng2, t2 = _traced_serve(seed_params=eng1.params)
+    e1 = obs_export.export_chrome_trace(t1)
+    assert e1 == obs_export.export_chrome_trace(t2)
+    doc = json.loads(e1)
+    assert obs_export.validate_chrome_trace(doc) == []
+    st = eng1.paging_stats
+    spans = obs_export.span_summary(doc)["spans"]
+    n = {name: spans[name]["n"] for name in SESSION_SPANS}
+    assert n["dispatch"] == n["fetch"] == n["commit_tokens"] \
+        == n["decode_chunk"] == st["decode_dispatches"] >= 1
+    slotted = st["request_timing"]["queue_s"]["count"]
+    assert n["prefill"] == n["commit_prefill"] == slotted == 3
+    assert n["admit"] >= 1 and n["ensure_pages"] == n["decode_chunk"]
+    tree = _span_tree(t1.events)
+    assert {p for name, p in tree if name in ("prefill",
+                                              "commit_prefill")} \
+        == {"admit"}
+    assert {p for name, p in tree if name in ("dispatch", "fetch",
+                                              "commit_tokens")} \
+        == {"decode_chunk"}
+    assert {p for name, p in tree if name in ("admit", "ensure_pages",
+                                              "decode_chunk")} == {None}
+    # within one chunk: launch, then wait, then commit
+    chunk_kids = [name for name, p in tree if p == "decode_chunk"]
+    assert chunk_kids == ["dispatch", "fetch", "commit_tokens"] \
+        * st["decode_dispatches"]
+
+
+class TickingClock(FakeClock):
+    """Advances one second on every read, so any two reads differ."""
+
+    def __call__(self):
+        self.t += 1.0
+        return self.t
+
+
+@pytest.mark.parametrize("traced", [False, True])
+def test_decode_split_counters_lie_within_the_dispatches(traced):
+    """decode_enqueue_s + decode_wait_s + decode_commit_s are always kept,
+    each phase is non-empty, and together they are at most the wall time
+    of the decode chunks (the traced run's decode_chunk spans, on the same
+    clock)."""
+    clock = TickingClock()
+    tracer = Tracer(clock=clock) if traced else None
+    cfg, eng = _engine(tracer=tracer)
+    eng.clock = clock
+    eng.serve(_reqs(cfg, 3))
+    st = eng.paging_stats
+    split = [st[k] for k in ("decode_enqueue_s", "decode_wait_s",
+                             "decode_commit_s")]
+    assert all(s >= st["decode_dispatches"] for s in split)
+    if traced:
+        wall = obs_export.span_summary(tracer)["spans"]["decode_chunk"]
+        assert sum(split) < wall["total_s"]
+    # the counters merge across replicas by sum
+    merged = merge_replica_stats([st, st])
+    assert merged["decode_wait_s"] == 2 * st["decode_wait_s"]
+
+
+def test_straggler_dispatch_logs_its_split(caplog):
+    """A dispatch the watchdog flags records where its time went: here
+    the launch stalls (the clock jumps inside ``_fused_decode``), so the
+    log and the trace's straggler_flagged instant name the enqueue."""
+    from repro.train.fault import FaultConfig
+    clock = FakeClock()
+    tracer = Tracer(clock=clock)
+    cfg = get_smoke("granite-3-2b")
+    eng = Engine(cfg, ServeConfig(max_seq=S_MAX, n_slots=2, page_size=PS,
+                                  decode_chunk=1, eos_id=-1),
+                 fault_cfg=FaultConfig(straggler_factor=2.0))
+    eng.clock, eng.tracer = clock, tracer
+    orig, calls = eng._fused_decode, []
+
+    def fused(*a):
+        out = orig(*a)
+        calls.append(1)
+        clock.advance(10.0 if len(calls) == 8 else 1.0)
+        return out
+
+    eng._fused_decode = fused
+    session = eng.start_session(_reqs(cfg, 2, max_new=12))
+    with caplog.at_level("WARNING", logger="repro.serve.engine"):
+        session.drain()
+    assert session.straggler_log == [(7, 10.0, 0.0, 0.0)]
+    flagged = [e for e in tracer.events if e["name"] == "straggler_flagged"]
+    assert [e["args"] for e in flagged] == [
+        {"step": 7, "enqueue_s": 10.0, "wait_s": 0.0, "commit_s": 0.0}]
+    assert "dispatch at step 7: enqueue 10.000s, wait 0.000s" in caplog.text
+
+
+def test_profiler_sink_nests_spans_on_the_host_plane(tmp_path):
+    """Tracer(profiler=True) under jax.profiler: every span lands on the
+    profiler's host plane as ``repro.serve.<name>``, nested as the
+    in-memory spans are."""
+    import jax
+
+    from repro.obs.trace import PROFILER_PREFIX
+    cfg, eng = _engine()
+    eng.serve(_reqs(cfg, 3, seed=5))          # compile outside the capture
+    tracer = Tracer(clock=eng.clock, profiler=True)
+    eng.tracer = tracer
+    with jax.profiler.trace(str(tmp_path)):
+        eng.serve(_reqs(cfg, 3))
+    pb = list(tmp_path.glob("plugins/profile/*/*.xplane.pb"))
+    assert len(pb) == 1
+    prof = jax.profiler.ProfileData.from_file(str(pb[0]))
+    evs = sorted(((e.start_ns, -e.duration_ns, e.start_ns + e.duration_ns,
+                   e.name[len(PROFILER_PREFIX):])
+                  for plane in prof.planes if plane.name.startswith("/host:")
+                  for line in plane.lines for e in line.events
+                  if e.name.startswith(PROFILER_PREFIX)))
+    tree, stack = [], []
+    for start, _, end, name in evs:
+        while stack and stack[-1][0] <= start:
+            stack.pop()
+        tree.append((name, stack[-1][1] if stack else None))
+        stack.append((end, name))
+    assert tree == _span_tree(tracer.events)
+    assert {name for name, _ in tree} == set(SESSION_SPANS)
 
 
 def test_session_stats_are_registry_backed_with_percentiles():
