@@ -146,11 +146,16 @@ def _kernel_in_program(mat, x) -> bool:
                                         interpret=False)
     else:
         plan = kops.get_plan(mat)
-        launch = rgcsr_spmv_pallas if x.ndim == 1 else rgcsr_spmm_pallas
-        lowered = launch.lower(
-            plan.step_group, plan.step_first, plan.values2d, plan.columns2d,
-            x, n_groups=plan.n_groups, group_size=plan.group_size,
-            chunks_per_step=plan.chunks_per_step, interpret=False)
+        args = (plan.step_group, plan.step_first, plan.values2d,
+                plan.columns2d, x)
+        layout = dict(n_groups=plan.n_groups, group_size=plan.group_size,
+                      chunks_per_step=plan.chunks_per_step, interpret=False)
+        if x.ndim == 1:
+            lowered = rgcsr_spmv_pallas.lower(
+                *args, plan.diag_start, plan.diag_shift,
+                diag_steps=plan.diag_steps, x_pad=plan.x_pad, **layout)
+        else:
+            lowered = rgcsr_spmm_pallas.lower(*args, **layout)
     return "tpu_custom_call" in lowered.as_text()
 
 

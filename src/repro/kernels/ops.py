@@ -22,8 +22,10 @@ identical semantics (the CPU test path).  ``interpret=None`` resolves via
 from __future__ import annotations
 
 import collections
+import concurrent.futures
 import dataclasses
 import functools
+import os
 import threading
 import weakref
 from typing import Any, Dict, Optional, Tuple
@@ -36,7 +38,8 @@ from repro.core.formats import ELLPACK, HybridEllCoo, RgCSR, ShardedRgCSR
 from repro.kernels.ell_spmv import ell_spmv_pallas
 from repro.kernels.rgcsr_spmm import rgcsr_spmm_pallas
 from repro.kernels.rgcsr_spmv import (CHUNKS_PER_STEP_CHOICES, LANES,
-                                      SUBLANES, rgcsr_spmv_pallas)
+                                      SUBLANES, merge_group_parts,
+                                      rgcsr_spmv_pallas)
 
 __all__ = ["RgCSRPlan", "make_plan", "rgcsr_spmv", "rgcsr_spmm",
            "EllPlan", "make_ell_plan", "ell_spmv", "hybrid_spmv",
@@ -64,6 +67,15 @@ class RgCSRPlan:
     ``s`` covers slot rows ``[R·s, R·(s+1))`` of ``values2d``/``columns2d``
     (``R = 8·chunks_per_step``) and belongs to group ``step_group[s]``.
 
+    **Diagonal slot rows** (block plans, DESIGN.md §3.1): steps ``[0,
+    diag_steps)`` hold rows whose lane ``l`` of group ``g`` is the entry at
+    column ``g·G + l + d`` for one offset ``d`` per row (or an exact 0);
+    ``diag_start`` gives each such row's start ``g·G + d + x_pad`` in x
+    padded by ``x_pad`` zeros on both sides.  Steps ``[diag_steps,
+    num_steps)`` hold the gathered rows.  Within each range a group's steps
+    are consecutive and ``step_first`` marks its first.  ``columns2d`` stays
+    valid on diagonal rows (true column, or an in-range column under a 0).
+
     **Adaptive plans** (``ordering='adaptive'``, DESIGN.md §5): groups hold
     length-sorted rows instead of consecutive ones, so the kernel's output
     lives in the *permuted* row space.  ``gather_idx``/``grouped_mask`` are
@@ -90,6 +102,13 @@ class RgCSRPlan:
     spill_values: Any = None       # (nnz_spill,)
     spill_rows: Any = None         # (nnz_spill,) int32 original row ids
     spill_columns: Any = None      # (nnz_spill,) int32
+    # --- diagonal slot rows (0/None: every row is gathered) ---
+    diag_steps: int = 0            # leading steps that hold diagonal rows
+    diag_start: Any = None         # (diag_steps·R,) int32 slice starts
+    # diag_start % G per step, the kernel's SMEM block: derived inside the
+    # SpMV program instead, its relayout took ~10 s of TPU compile
+    diag_shift: Any = None         # (diag_steps, 1, R) int32
+    x_pad: int = 0                 # zeros on each side of the sliced x
 
     @property
     def num_steps(self) -> int:
@@ -109,6 +128,15 @@ class RgCSRPlan:
     def n_spilled_elements(self) -> int:
         return 0 if self.spill_values is None else int(
             self.spill_values.shape[0])
+
+    @property
+    def diag_slot_fraction(self) -> float:
+        """Share of stored slot rows whose x comes from contiguous slices
+        (diagonal rows) instead of the scalar gather."""
+        if self.stored_slots == 0:
+            return 0.0
+        return (self.diag_steps * self.chunks_per_step * SUBLANES
+                / self.stored_slots)
 
     @property
     def stored_elements(self) -> int:
@@ -169,51 +197,304 @@ def make_plan(m: RgCSR, *, chunks_per_step: int = 1,
         raise ValueError(
             "spill_threshold requires ordering='adaptive' (block grouping "
             "cannot drop rows without a permutation gather)")
+    return _make_block_plan(m, chunks_per_step=chunks_per_step,
+                            offset_slots=True)
+
+
+def _make_block_plan(m: RgCSR, *, chunks_per_step: int,
+                     offset_slots: bool) -> RgCSRPlan:
+    """Block plan: group ``g`` holds rows ``[g·G, (g+1)·G)``.
+
+    With ``offset_slots`` the groups whose rows share column offsets get
+    diagonal slot rows (:func:`_offset_slots`); a matrix where none does
+    keeps the CSR slotting of ``m`` as it is.
+    """
     g = m.group_size
     rows_per_step = chunks_per_step * SUBLANES
-    slots = np.asarray(m.slots_per_group)
-    n_groups = len(slots)
+    slots = np.asarray(m.slots_per_group).astype(np.int64)
     total_slots = int(slots.sum())
     values2d = np.asarray(m.values).reshape(total_slots, g)
     columns2d = np.asarray(m.columns).reshape(total_slots, g).astype(np.int32)
+    base = dict(n_rows=m.shape[0], n_cols=m.shape[1], n_groups=m.n_groups,
+                group_size=g, chunks_per_step=chunks_per_step, nnz=m.nnz)
 
-    padded = (-(-slots // rows_per_step) * rows_per_step).astype(np.int64)
-    if int(padded.sum()) != total_slots:
-        # re-pad each group's tile up to the coarsened step granularity
-        src_off = np.concatenate([[0], np.cumsum(slots)[:-1]])
-        dst_off = np.concatenate([[0], np.cumsum(padded)[:-1]])
-        vp = np.zeros((int(padded.sum()), g), values2d.dtype)
-        cp = np.zeros((int(padded.sum()), g), np.int32)
-        for gi in range(n_groups):
-            k = int(slots[gi])
-            vp[dst_off[gi]: dst_off[gi] + k] = values2d[src_off[gi]: src_off[gi] + k]
-            cp[dst_off[gi]: dst_off[gi] + k] = columns2d[src_off[gi]: src_off[gi] + k]
-        values2d, columns2d = vp, cp
+    diag = _offset_slots(values2d, columns2d, slots,
+                         np.asarray(m.row_lengths), group_size=g,
+                         rows_per_step=rows_per_step,
+                         n_cols=m.shape[1]) if offset_slots else None
+    if diag is not None:
+        sg_d, sf_d = _step_table(diag["diag_rows"], rows_per_step)
+        sg_g, sf_g = _step_table(diag["gathered_rows"], rows_per_step)
+        return RgCSRPlan(
+            values2d=_stack(diag["values2d"]),
+            columns2d=_stack(diag["columns2d"]),
+            step_group=jnp.asarray(np.concatenate([sg_d, sg_g])),
+            step_first=jnp.asarray(np.concatenate([sf_d, sf_g])),
+            diag_steps=len(sg_d),
+            diag_start=jnp.asarray(diag["diag_start"]),
+            diag_shift=jnp.asarray((diag["diag_start"] % g).reshape(
+                len(sg_d), 1, rows_per_step)),
+            x_pad=diag["x_pad"], **base)
 
+    values2d, columns2d, padded = _csr_rows(values2d, columns2d, slots,
+                                            rows_per_step)
     step_group, step_first = _step_table(padded, rows_per_step)
     return RgCSRPlan(
         values2d=jnp.asarray(values2d),
         columns2d=jnp.asarray(columns2d),
         step_group=jnp.asarray(step_group),
         step_first=jnp.asarray(step_first),
-        n_rows=m.shape[0],
-        n_cols=m.shape[1],
-        n_groups=m.n_groups,
-        group_size=g,
-        chunks_per_step=chunks_per_step,
-        nnz=m.nnz,
+        **base,
     )
 
 
+def _stack(blocks):
+    """Host blocks stacked on the device (one transfer each, no host copy)."""
+    return jnp.concatenate([jnp.asarray(b) for b in blocks])
+
+
+def _csr_rows(values2d, columns2d, slots, rows_per_step: int):
+    """CSR-slotted storage with each group's rows re-padded to whole steps
+    (exact zeros, ghost column 0); returns it and the padded row counts."""
+    padded = _pad_to(slots, rows_per_step)
+    if int(padded.sum()) == len(values2d):
+        return values2d, columns2d, padded
+    grown = padded - slots
+    dst = np.arange(len(values2d)) + np.repeat(np.cumsum(grown) - grown,
+                                               slots)
+    vp = np.zeros((int(padded.sum()), values2d.shape[1]), values2d.dtype)
+    cp = np.zeros(vp.shape, np.int32)
+    vp[dst], cp[dst] = values2d, columns2d
+    return vp, cp, padded
+
+
+# Slot rows per host thread when re-slotting (up to 8 threads).
+_SLOT_ROWS_PER_PART = 1 << 16
+
+
+def _offset_slots(values2d, columns2d, slots, row_lens, *, group_size: int,
+                  rows_per_step: int, n_cols: int):
+    """Re-slot the groups whose rows share column offsets (DESIGN.md §3.1).
+
+    ``values2d``/``columns2d``: the CSR-slotted ``(S, G)`` storage, group
+    ``g`` owning ``slots[g]`` rows and lane ``l`` its row's entries in slots
+    ``[0, row_lens[g·G + l])``.  Groups are independent, so runs of them
+    are re-slotted on host threads (:func:`_offset_slots_part`).
+
+    Returns None when no group takes a diagonal row, else the new storage
+    as blocks to stack (diagonal rows of every group first, then gathered
+    rows), each group's padded row counts in both parts, the diagonal
+    rows' slice starts in x padded by ``x_pad`` on both sides, and
+    ``x_pad``.
+    """
+    g = group_size
+    n_groups = len(slots)
+    lens = np.zeros(n_groups * g, np.int32)
+    lens[: len(row_lens)] = row_lens
+    lens = lens.reshape(n_groups, g)
+    first = np.concatenate([[0], np.cumsum(slots)])
+    # runs of groups of about equal storage, re-slotted on host threads
+    n_parts = max(1, min(8, int(first[-1]) // _SLOT_ROWS_PER_PART))
+    cut = np.unique(np.searchsorted(
+        first, np.linspace(0, first[-1], n_parts + 1)[1:-1]))
+    edges = [0, *cut[(cut > 0) & (cut < n_groups)].tolist(), n_groups]
+
+    def part(lo, hi):
+        rows = slice(first[lo], first[hi])
+        return _offset_slots_part(
+            values2d[rows], columns2d[rows], slots[lo:hi], lens[lo:hi],
+            first_group=lo, rows_per_step=rows_per_step, n_cols=n_cols)
+
+    with concurrent.futures.ThreadPoolExecutor(
+            min(len(edges) - 1, os.cpu_count() or 1)) as pool:
+        parts = list(pool.map(part, edges[:-1], edges[1:]))
+    if all(p is None for p in parts):
+        return None
+    for i, (lo, hi) in enumerate(zip(edges[:-1], edges[1:])):
+        if parts[i] is None:            # no diagonal row: CSR slotting
+            rows = slice(first[lo], first[hi])
+            vg, cg, padded = _csr_rows(values2d[rows], columns2d[rows],
+                                       slots[lo:hi], rows_per_step)
+            parts[i] = dict(vd=vg[:0], cd=cg[:0], start=np.zeros(0, np.int32),
+                            vg=vg, cg=cg, diag_rows=np.zeros_like(padded),
+                            gathered_rows=padded)
+    start = np.concatenate([p["start"] for p in parts])
+    x_pad = int(max(0, -int(start.min()), int(start.max()) + g - n_cols))
+    return dict(values2d=[p["vd"] for p in parts] + [p["vg"] for p in parts],
+                columns2d=[p["cd"] for p in parts] + [p["cg"] for p in parts],
+                diag_rows=np.concatenate([p["diag_rows"] for p in parts]),
+                gathered_rows=np.concatenate(
+                    [p["gathered_rows"] for p in parts]),
+                diag_start=start + np.int32(x_pad), x_pad=x_pad)
+
+
+def _offset_slots_part(values2d, columns2d, slots, lens, *, first_group: int,
+                       rows_per_step: int, n_cols: int):
+    """Diagonal slot rows for a run of groups starting at ``first_group``
+    (``lens``: its ``(n, G)`` row lengths).  Per group:
+
+    1. candidates are the offsets ``d = column − row`` of its longest row;
+       a lane's entry matches a candidate positionally (same slot, same
+       offset) or, where rows differ in which offsets they have, by lookup;
+    2. candidates are ranked by how many lanes they serve, and the group
+       takes the ranked prefix of ``t`` diagonal rows that minimises its
+       padded slot count ``pad_R(t) + pad_R(max leftover per lane)``,
+       preferring more diagonal rows on ties.  ``t = 0`` is the CSR
+       slotting, so no group grows.  A prefix qualifies only if every
+       offset in it serves at least two lanes and the rows serve, on
+       average, at least half of the group's non-empty lanes: offsets
+       that rows share by chance (random and power-law rows) would leave
+       the diagonal rows nearly empty;
+    3. diagonal rows keep candidate (CSR) order, so for rows with ascending
+       columns the summation order is unchanged but for added zeros; the
+       leftover entries stay in CSR order in the group's gathered rows.
+
+    Returns None when no group of the run takes a diagonal row, else its
+    diagonal block (values, columns, slice starts ``g·G + d``) and
+    gathered block, and each group's padded row counts in both.  int32
+    on ``(S, G)`` arrays throughout.
+    """
+    g = values2d.shape[1]
+
+    def pad(k):                                  # whole steps; 0 stays 0
+        return _pad_to(k, rows_per_step)
+
+    n_slots = values2d.shape[0]
+    n_groups = len(slots)
+    slots = np.asarray(slots, np.int64)
+    first = np.zeros(n_groups, np.int64)
+    first[1:] = np.cumsum(slots)[:-1]
+    grp = np.repeat(np.arange(n_groups, dtype=np.int32), slots)
+    k = np.arange(n_slots, dtype=np.int32) - first[grp].astype(np.int32)
+    n_cand = lens.max(axis=1)
+    group_row0 = (first_group + np.arange(n_groups, dtype=np.int32)) * g
+    row0 = group_row0[grp]
+    # few fresh (S, G) arrays, filled in place: each costs page faults
+    offset = np.take(lens, grp, axis=0)
+    valid = offset > k[:, None]
+    np.subtract(columns2d, row0[:, None], out=offset)
+    offset -= np.arange(g, dtype=np.int32)
+    is_cand = k < n_cand[grp]
+    cand = offset[np.arange(n_slots), lens.argmax(axis=1)[grp]]   # (S,)
+
+    # hits[r, l]: lane l has an entry at candidate row r's offset
+    positional = offset == cand[:, None]
+    positional &= valid
+    positional &= is_cand[:, None]
+    hits = positional.copy()
+    unmatched = np.greater(valid, positional)     # valid and not positional
+    some = np.nonzero(unmatched.any(axis=1))[0]
+    src_r, src_l = np.nonzero(unmatched[some])
+    src_r = some[src_r]
+    tgt_r = np.zeros(0, np.int64)
+    if len(src_r):
+        # look the rest up among the group's candidates: key (group, offset)
+        shift = np.int64(first_group + n_groups) * g
+        span = np.int64(n_cols) + shift + 1
+        crow = np.nonzero(is_cand)[0]
+        ckey = grp[crow].astype(np.int64) * span + cand[crow] + shift
+        order = np.argsort(ckey, kind="stable")
+        ckey, crow = ckey[order], crow[order]
+        qkey = grp[src_r].astype(np.int64) * span + offset[src_r, src_l] \
+            + shift
+        at = np.minimum(np.searchsorted(ckey, qkey), len(ckey) - 1)
+        tgt = crow[at]
+        # a repeated column keeps its slot: one entry per (candidate, lane)
+        found = np.nonzero((ckey[at] == qkey) & ~positional[tgt, src_l])[0]
+        _, once = np.unique(tgt[found] * np.int64(g) + src_l[found],
+                            return_index=True)
+        found = found[once]
+        src_r, src_l, tgt_r = src_r[found], src_l[found], tgt[found]
+        hits[tgt_r, src_l] = True
+
+    # rank candidates by lanes served; cost of each ranked prefix
+    cover = np.count_nonzero(hits, axis=1).astype(np.int32)
+    cover[~is_cand] = 0
+    rank = np.lexsort((k, -cover, grp))          # stays within each group
+    # most_left[p]: the longest lane's leftover once its group's ranked
+    # candidates up to position p are diagonal rows; one pass per rank
+    # position over the groups that have it
+    most_left = np.empty(n_slots, np.int32)
+    by_slots = np.argsort(-slots, kind="stable")
+    n_with = np.searchsorted(-slots[by_slots], -np.arange(int(slots.max())),
+                             side="left")
+    left = lens[by_slots]
+    for j, n_j in enumerate(n_with):
+        at = first[by_slots[:n_j]] + j
+        left[:n_j] -= hits[rank[at]]
+        most_left[at] = left[:n_j].max(axis=1)
+    t = k + 1                                    # prefix size at each rank
+    served = np.cumsum(cover[rank], dtype=np.int64)
+    served -= np.concatenate([[0], served[first[1:] - 1]])[grp]
+    active = (lens > 0).sum(axis=1)
+    ok = (cover[rank] >= 2) & (2 * served >= t * active[grp].astype(np.int64))
+    cost = pad(t.astype(np.int64)) + pad(most_left.astype(np.int64))
+    top = int(slots.max()) + 1
+    never = np.iinfo(np.int64).max
+    best = np.minimum.reduceat(np.where(ok, cost * top + (top - t), never),
+                               first)
+    cost0 = pad(np.maximum(n_cand, 1).astype(np.int64))
+    take = (best != never) & (best // top <= cost0)
+    if not take.any():
+        return None
+    n_diag = np.where(take, top - best % top, 0)
+    chosen = np.zeros(n_slots, bool)
+    chosen[rank] = k < n_diag[grp]
+
+    # --- diagonal rows: the chosen candidates in candidate order
+    diag_rows = pad(n_diag)
+    left_at_take = most_left[first + np.maximum(n_diag, 1) - 1]
+    gathered_rows = np.where(take, pad(left_at_take.astype(np.int64)),
+                             pad(slots))       # as _csr_rows, if not taken
+    vd = np.zeros((int(diag_rows.sum()), g), values2d.dtype)
+    cd = np.empty(vd.shape, np.int32)
+    dfirst = np.concatenate([[0], np.cumsum(diag_rows)[:-1]])
+    sel = np.nonzero(chosen)[0]
+    n_sel = np.cumsum(chosen)
+    dst = dfirst[grp[sel]] + n_sel[sel] - 1 - np.concatenate(
+        [[0], n_sel[first[1:] - 1]])[grp[sel]]
+    start = np.repeat(group_row0, diag_rows)     # g·G + d; pad rows d = 0
+    start[dst] += cand[sel]
+    picked = np.take(values2d, sel, axis=0)
+    np.copyto(picked, 0, where=~np.take(positional, sel, axis=0))
+    vd[dst] = picked
+    looked = chosen[tgt_r]
+    where = np.zeros(n_slots, np.int64)
+    where[sel] = dst
+    vd[where[tgt_r[looked]], src_l[looked]] = \
+        values2d[src_r[looked], src_l[looked]]
+    np.add(start[:, None], np.arange(g, dtype=np.int32), out=cd)
+    np.clip(cd, 0, max(n_cols - 1, 0), out=cd)
+
+    # --- gathered rows: the rest, compacted per lane in CSR order
+    vg = np.zeros((int(gathered_rows.sum()), g), values2d.dtype)
+    cg = np.zeros(vg.shape, np.int32)
+    if len(vg):
+        used = positional & chosen[:, None]
+        used[src_r[looked], src_l[looked]] = True
+        rest = valid & ~used
+        gfirst = np.concatenate([[0], np.cumsum(gathered_rows)[:-1]])
+        upto = np.cumsum(rest, axis=0, dtype=np.int32)
+        before = np.zeros((n_groups, g), np.int32)
+        before[1:] = upto[first[1:] - 1]
+        rr, ll = np.nonzero(rest)
+        out = gfirst[grp[rr]] + upto[rr, ll] - 1 - before[grp[rr], ll]
+        vg[out, ll] = values2d[rr, ll]
+        cg[out, ll] = columns2d[rr, ll]
+    return dict(vd=vd, cd=cd, start=start, vg=vg, cg=cg,
+                diag_rows=diag_rows, gathered_rows=gathered_rows)
+
+
 def _step_table(padded_slots: np.ndarray, rows_per_step: int):
-    """(step_group, step_first) for per-group padded slot counts."""
+    """(step_group, step_first) for per-group padded slot counts (a group
+    with 0 slots gets no step)."""
     steps_per_group = (padded_slots // rows_per_step).astype(np.int64)
     n_groups = len(steps_per_group)
     step_group = np.repeat(np.arange(n_groups, dtype=np.int32),
                            steps_per_group)
     first_idx = np.cumsum(np.concatenate([[0], steps_per_group[:-1]]))
     step_first = np.zeros(len(step_group), dtype=np.int32)
-    step_first[first_idx] = 1
+    step_first[first_idx[steps_per_group > 0]] = 1
     return step_group, step_first
 
 
@@ -433,8 +714,9 @@ def rgcsr_spmv(plan: RgCSRPlan, x, *, interpret: bool | None = None):
     x = jnp.asarray(x)
     y_flat = rgcsr_spmv_pallas(
         plan.step_group, plan.step_first, plan.values2d, plan.columns2d,
-        x, n_groups=plan.n_groups, group_size=plan.group_size,
-        chunks_per_step=plan.chunks_per_step, interpret=interpret)
+        x, plan.diag_start, plan.diag_shift, n_groups=plan.n_groups,
+        group_size=plan.group_size, chunks_per_step=plan.chunks_per_step,
+        diag_steps=plan.diag_steps, x_pad=plan.x_pad, interpret=interpret)
     if plan.ordering != "adaptive":
         return y_flat[: plan.n_rows]
     return _adaptive_finish_spmv(
@@ -445,15 +727,30 @@ def rgcsr_spmv(plan: RgCSRPlan, x, *, interpret: bool | None = None):
 
 def rgcsr_spmm(plan: RgCSRPlan, x, *, d_tile: int = LANES,
                interpret: bool | None = None):
-    """Y = A @ X via the Pallas kernel. X: (n_cols, d) -> Y: (n_rows, d)."""
+    """Y = A @ X via the Pallas kernel. X: (n_cols, d) -> Y: (n_rows, d).
+
+    The kernel gathers X through ``columns2d`` for every slot row; a plan
+    with both diagonal and gathered rows runs it once per step range."""
     if interpret is None:
         interpret = default_interpret()
     x = jnp.asarray(x)
-    y = rgcsr_spmm_pallas(
-        plan.step_group, plan.step_first, plan.values2d, plan.columns2d,
-        x, n_groups=plan.n_groups, group_size=plan.group_size,
-        d_tile=d_tile, chunks_per_step=plan.chunks_per_step,
-        interpret=interpret)
+    launch = functools.partial(
+        rgcsr_spmm_pallas, x=x, n_groups=plan.n_groups,
+        group_size=plan.group_size, d_tile=d_tile,
+        chunks_per_step=plan.chunks_per_step, interpret=interpret)
+    cut = plan.diag_steps
+    if 0 < cut < plan.num_steps:
+        r = cut * plan.chunks_per_step * SUBLANES
+        head, tail = slice(None, cut), slice(cut, None)
+        parts = [(launch(plan.step_group[st], plan.step_first[st],
+                         plan.values2d[rows], plan.columns2d[rows]
+                         ).reshape(plan.n_groups, -1), plan.step_group[st])
+                 for st, rows in ((head, slice(None, r)),
+                                  (tail, slice(r, None)))]
+        y = merge_group_parts(parts, plan.n_groups).reshape(-1, x.shape[1])
+    else:
+        y = launch(plan.step_group, plan.step_first, plan.values2d,
+                   plan.columns2d)
     if plan.ordering != "adaptive":
         return y[: plan.n_rows]
     return _adaptive_finish_spmm(
@@ -471,8 +768,9 @@ def rgcsr_spmm(plan: RgCSRPlan, x, *, d_tile: int = LANES,
 class ShardedRgCSRPlan:
     """Stacked, device-major execution plan for a :class:`ShardedRgCSR`.
 
-    Each shard's :class:`RgCSRPlan` (built by the unchanged ``make_plan`` —
-    block or adaptive grouping applies *per shard*, at that shard's own
+    Each shard's :class:`RgCSRPlan` (built by the single-device planner in
+    CSR slotting, without diagonal rows — block or adaptive grouping
+    applies *per shard*, at that shard's own
     tuned ``(chunks_per_step, ordering, spill_threshold)`` from
     ``shard_configs``) is padded to the across-shard maxima and stacked on
     a leading device axis, which is what ``shard_map`` needs: one SPMD
@@ -775,7 +1073,11 @@ def make_sharded_plan(sm: ShardedRgCSR, *, chunks_per_step: int = 1,
         send_idx = edge_counts = None
         e_max = e_tail = r_max = 0
 
-    plans = [make_plan(src, chunks_per_step=c[0], ordering=c[1],
+    # shards keep CSR slotting: the SPMD kernel runs one gathered step
+    # table per shard
+    plans = [_make_block_plan(src, chunks_per_step=c[0], offset_slots=False)
+             if c[1] == "block" and not c[2] else
+             make_plan(src, chunks_per_step=c[0], ordering=c[1],
                        spill_threshold=c[2])
              for src, c in zip(sources, cfgs)]
     # expand each shard's step table to the kernel cps: one coarse step of

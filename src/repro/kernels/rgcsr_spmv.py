@@ -34,13 +34,34 @@ the cost is padding short groups up to the coarsened tile (masked by exact
 zeros placed at plan time via the chunk table).  The autotuner
 (:mod:`repro.kernels.autotune`) measures this trade per matrix.
 
-**The x gather runs in XLA, before the kernel.**  The TPU compiler has no
-general in-kernel gather (Mosaic accepts only 2-D gathers within a tile),
-so ``x[columns2d]`` is formed by one XLA gather into an ``(S, G)`` stream
-laid out exactly like the value tile, and the kernel multiplies the two
-``(R, G)`` tiles and reduces over slots.  x itself is never staged into
-VMEM, so its width is not bounded by VMEM.  The price is one extra
-``(S, G)`` stream of HBM bytes (written by the gather, read by the kernel).
+**The x stream is built in XLA, before the kernel, in one of two ways.**
+The TPU compiler has no general in-kernel gather (Mosaic accepts only 2-D
+gathers within a tile), so x reaches the kernel as an ``(S, G)`` stream laid
+out like the value tile:
+
+* *Diagonal slot rows* (block plans of matrices whose rows share column
+  offsets, e.g. stencils — DESIGN.md §3.1) hold, in lane ``l`` of group
+  ``g``, the entry at column ``g·G + l + d`` for one offset ``d`` per row,
+  or an exact 0.  Their x is the contiguous slice
+  ``x_pad[start : start + G]`` of a zero-padded x (``start = g·G + d +
+  pad``).  XLA fetches the two aligned ``G``-wide rows of ``x_pad`` that the
+  slice spans (a row gather, DMA-friendly), and
+  :func:`rgcsr_diag_spmv_kernel` rotates them into place per slot row with
+  ``pltpu.roll`` by the row's ``start % G``, scalar-prefetched per step
+  from SMEM.  The plan (``ops._offset_slots``) picks, per group, offsets of
+  its longest row ranked by how many lanes share them, as many as keep the
+  group's padded slot count smallest (ties go to more diagonal rows), pads
+  x by its largest overhang past 0 or n, and puts diagonal rows first, in
+  steps ``[0, diag_steps)``.
+* *Gathered slot rows* (everything else) take ``x[columns2d]`` from one
+  scalar XLA gather, and :func:`rgcsr_spmv_kernel` multiplies the two
+  ``(R, G)`` tiles and reduces over slots.
+
+x itself is never staged into VMEM, so its width is not bounded by VMEM.
+The price is extra ``(S, G)``-sized streams of HBM bytes (two for diagonal
+rows, one for gathered rows), written by XLA and read by the kernel.  When a
+plan has both kinds, each kernel writes the groups its steps visit, and the
+two outputs are combined per group.
 
 Scalar-prefetch carries ``step_group`` (output index map) and ``step_first``
 (accumulator init).  The output is ``(n_groups, 1, G)`` float32 — a
@@ -65,6 +86,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -74,7 +96,8 @@ LANES = 128
 # Candidate coarsening factors: how many 8-slot chunks one grid step covers.
 CHUNKS_PER_STEP_CHOICES = (1, 2, 4, 8)
 
-__all__ = ["rgcsr_spmv_kernel", "rgcsr_spmv_pallas",
+__all__ = ["rgcsr_spmv_kernel", "rgcsr_diag_spmv_kernel",
+           "rgcsr_spmv_pallas", "merge_group_parts",
            "CHUNKS_PER_STEP_CHOICES", "SUBLANES", "LANES"]
 
 
@@ -96,14 +119,75 @@ def rgcsr_spmv_kernel(step_group_ref, step_first_ref,
     y_ref[...] += jnp.sum(prods, axis=0, keepdims=True)
 
 
+def rgcsr_diag_spmv_kernel(step_group_ref, step_first_ref, shift_ref,
+                           values_ref, xa_ref, xb_ref, y_ref):
+    """Kernel body for diagonal slot rows.
+
+    Blocks: values and the two aligned x rows ``xa``/``xb`` ``(R, G)``;
+    ``shift`` ``(1, R)`` int32 in SMEM, the lane at which each row's slice
+    starts inside ``xa``; y ``(1, G)`` float32 accumulator.  Slot row ``i``
+    multiplies its values with ``concat(xa[i], xb[i])[shift : shift + G]``.
+    """
+    s = pl.program_id(0)
+
+    @pl.when(step_first_ref[s] == 1)
+    def _init():
+        y_ref[...] = jnp.zeros_like(y_ref)
+
+    rows, g = values_ref.shape
+    lane = lax.broadcasted_iota(jnp.int32, (1, g), 1)
+    row = lambda ref, i: ref[pl.ds(i, 1), :].astype(jnp.float32)  # noqa
+    acc = jnp.zeros((1, g), jnp.float32)
+    for i in range(rows):
+        k = shift_ref[0, i]
+        # lanes >= k come from xa, the rest from xb; rolling left by k
+        # then puts lane k + l of the pair at lane l
+        x = jnp.where(lane >= k, row(xa_ref, i), row(xb_ref, i))
+        acc = acc + row(values_ref, i) * pltpu.roll(x, lax.rem(g - k, g), 1)
+    y_ref[...] += acc
+
+
+def merge_group_parts(parts, n_groups: int):
+    """Sum per-group outputs of kernels that each visit only some groups.
+
+    ``parts``: ``(y, step_group)`` pairs, ``y`` with leading axis
+    ``n_groups``; a group no step of a part visits holds whatever the output
+    buffer held, so it is masked out of that part.
+    """
+    if len(parts) == 1:
+        return parts[0][0]
+    total = None
+    for y, groups in parts:
+        seen = jnp.zeros((n_groups,), bool).at[groups].set(True)
+        y = jnp.where(seen.reshape((n_groups,) + (1,) * (y.ndim - 1)), y,
+                      jnp.zeros((), y.dtype))
+        total = y if total is None else total + y
+    return total
+
+
+def _diag_x(x, diag_start, *, group_size: int, x_pad: int):
+    """The two aligned ``G``-wide rows of the padded x that each diagonal
+    row's slice spans."""
+    g = group_size
+    n = x.shape[0]
+    n_rows = -(-(n + 2 * x_pad) // g) + 1      # room for row q + 1
+    x2 = jnp.pad(x, (x_pad, n_rows * g - n - x_pad)).reshape(n_rows, g)
+    q = diag_start // g
+    xa = jnp.take(x2, q, axis=0, mode="clip")
+    xb = jnp.take(x2, q + 1, axis=0, mode="clip")
+    return xa, xb
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("n_groups", "group_size", "chunks_per_step",
-                     "interpret"))
+                     "diag_steps", "x_pad", "interpret"))
 def rgcsr_spmv_pallas(step_group, step_first, values2d, columns2d, x,
-                      *, n_groups: int, group_size: int,
-                      chunks_per_step: int = 1, interpret: bool = True):
-    """Launch the RgCSR SpMV kernel.
+                      diag_start=None, diag_shift=None, *, n_groups: int,
+                      group_size: int, chunks_per_step: int = 1,
+                      diag_steps: int = 0, x_pad: int = 0,
+                      interpret: bool = True):
+    """Launch the RgCSR SpMV kernels.
 
     Args:
       step_group:   (num_steps,) int32 — group id of each coarsened step.
@@ -112,7 +196,14 @@ def rgcsr_spmv_pallas(step_group, step_first, values2d, columns2d, x,
                     group's slot count is a multiple of 8·chunks_per_step).
       columns2d:    (S, G) int32 column indices (ghost index 0 on padding).
       x:            (n_cols,) the dense vector.
+      diag_start:   (diag_steps · R,) int32 — where each diagonal slot row's
+                    x slice starts in x padded by ``x_pad`` zeros on both
+                    sides; None when the plan has no diagonal rows.
+      diag_shift:   (diag_steps, 1, R) int32 — ``diag_start % G`` per step.
       n_groups, group_size, chunks_per_step: static layout parameters.
+      diag_steps:   steps ``[0, diag_steps)`` hold diagonal rows, the rest
+                    gathered rows.
+      x_pad:        zeros on each side of x that keep every slice in range.
       interpret:    run in interpret mode (CPU validation) or compile for TPU.
 
     Returns:
@@ -122,23 +213,48 @@ def rgcsr_spmv_pallas(step_group, step_first, values2d, columns2d, x,
     g = group_size
     rows_per_step = chunks_per_step * SUBLANES
     out_dtype = jnp.result_type(values2d.dtype, x.dtype)
-    xg = jnp.take(x, columns2d, axis=0)                   # (S, G) XLA gather
+    n_steps = step_group.shape[0]
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(step_group.shape[0],),
-        in_specs=[
-            pl.BlockSpec((rows_per_step, g), lambda s, sg, sf: (s, 0)),
-            pl.BlockSpec((rows_per_step, g), lambda s, sg, sf: (s, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, 1, g),
-                               lambda s, sg, sf: (sg[s], 0, 0)),
-    )
-    y = pl.pallas_call(
-        rgcsr_spmv_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_groups, 1, g), jnp.float32),
-        interpret=interpret,
-        name="rgcsr_spmv",
-    )(step_group, step_first, values2d, xg)
+    def tile(first_step):
+        return pl.BlockSpec((rows_per_step, g),
+                            lambda s, sg, sf: (s + first_step, 0))
+
+    out_spec = pl.BlockSpec((None, 1, g), lambda s, sg, sf: (sg[s], 0, 0))
+    out_shape = jax.ShapeDtypeStruct((n_groups, 1, g), jnp.float32)
+    parts = []
+    if diag_steps:
+        with jax.named_scope("rgcsr_diag_x"):
+            xa, xb = _diag_x(x, diag_start, group_size=g, x_pad=x_pad)
+        shift_spec = pl.BlockSpec((None, 1, rows_per_step),
+                                  lambda s, sg, sf: (s, 0, 0),
+                                  memory_space=pltpu.SMEM)
+        sg = step_group[:diag_steps]
+        y = pl.pallas_call(
+            rgcsr_diag_spmv_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=(diag_steps,),
+                in_specs=[shift_spec, tile(0), tile(0), tile(0)],
+                out_specs=out_spec),
+            out_shape=out_shape,
+            interpret=interpret,
+            name="rgcsr_diag_spmv",
+        )(sg, step_first[:diag_steps], diag_shift, values2d, xa, xb)
+        parts.append((y, sg))
+    if diag_steps < n_steps:
+        cols = columns2d[diag_steps * rows_per_step:] if diag_steps \
+            else columns2d
+        xg = jnp.take(x, cols, axis=0)                # (S_g, G) XLA gather
+        sg = step_group[diag_steps:]
+        y = pl.pallas_call(
+            rgcsr_spmv_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=(n_steps - diag_steps,),
+                in_specs=[tile(diag_steps), tile(0)],
+                out_specs=out_spec),
+            out_shape=out_shape,
+            interpret=interpret,
+            name="rgcsr_spmv",
+        )(sg, step_first[diag_steps:], values2d, xg)
+        parts.append((y, sg))
+    y = merge_group_parts(parts, n_groups)
     return y.reshape(-1).astype(out_dtype)

@@ -32,8 +32,9 @@ from repro.serve import ServeConfig, device_loop, paging
 V5E_HBM_BYTES = 16 * 2 ** 30
 
 # 27-point stencil on a 64³ grid as RgCSR at G=128: 262,144 rows in 2048
-# groups, 27 nonzeros in interior rows → 32 slots per group at slot_pad 8
-STENCIL = dict(n=262_144, n_groups=2048, slots=32)
+# groups, 27 nonzeros in interior rows → 32 slots per group at slot_pad 8;
+# its diagonal rows' slices overhang by at most 64² + 64 + 1
+STENCIL = dict(n=262_144, n_groups=2048, slots=32, x_pad=4161)
 # granite-3-2b FFN weight (8192 × 2048) at density 0.25: 64 groups; the
 # longest of 128 rows with ~Binomial(2048, 0.25) nonzeros is ≈570 → 576
 FFN = dict(d_out=8192, d_in=2048, d=128, n_groups=64, slots=576)
@@ -69,6 +70,19 @@ def _sds(sharding, shape, dtype):
 @pytest.mark.parametrize("dtype,cps", [(jnp.float32, 1), (jnp.float32, 4),
                                        (jnp.bfloat16, 8)])
 def test_rgcsr_spmv_compiles_for_v5e(one_chip, dtype, cps):
+    _compile_spmv(one_chip, dtype, cps, diag_steps=0)
+
+
+@pytest.mark.parametrize("dtype,cps,part", [
+    (jnp.float32, 4, "all"),       # the stencil's plan: every row diagonal
+    (jnp.bfloat16, 1, "half")])    # both kernels, outputs merged per group
+def test_rgcsr_diag_spmv_compiles_for_v5e(one_chip, dtype, cps, part):
+    steps = STENCIL["n_groups"] * STENCIL["slots"] // (8 * cps)
+    _compile_spmv(one_chip, dtype, cps,
+                  diag_steps=steps if part == "all" else steps // 2)
+
+
+def _compile_spmv(one_chip, dtype, cps, *, diag_steps):
     s = STENCIL["n_groups"] * STENCIL["slots"]
     steps = s // (8 * cps)
     compiled = rgcsr_spmv_pallas.lower(
@@ -77,9 +91,15 @@ def test_rgcsr_spmv_compiles_for_v5e(one_chip, dtype, cps):
         _sds(one_chip, (s, 128), dtype),
         _sds(one_chip, (s, 128), jnp.int32),
         _sds(one_chip, (STENCIL["n"],), dtype),
+        *((_sds(one_chip, (diag_steps * 8 * cps,), jnp.int32),
+           _sds(one_chip, (diag_steps, 1, 8 * cps), jnp.int32))
+          if diag_steps else ()),
         n_groups=STENCIL["n_groups"], group_size=128, chunks_per_step=cps,
+        diag_steps=diag_steps, x_pad=STENCIL["x_pad"] if diag_steps else 0,
         interpret=False).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert ("rgcsr_diag_spmv" in text) == bool(diag_steps)
 
 
 @pytest.mark.parametrize("cps", [1, 4])
